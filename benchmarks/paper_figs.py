@@ -326,7 +326,6 @@ def throughput_sweep(op_counts=(4_096, 16_384, 65_536), records=60_000,
                          sim_ops_per_s=n_ops / wall,
                          compiles_warmup=warm.count,
                          compiles_measured=meas.count,
-                         compile_counter_available=meas.available,
                          mops_sim=r.mops, p99_us=r.p99_us)
             results.append(entry)
             print(f"{system:10s} {n_ops:7d} {wall:8.2f} "

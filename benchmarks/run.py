@@ -23,6 +23,8 @@ def main(argv=None) -> None:
                     help="also write rows to PATH (default "
                          "BENCH_paper_figs.json with --json '')")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import paper_figs as F
 
     n = 2_048 if args.quick else 6_144

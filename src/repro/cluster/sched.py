@@ -34,6 +34,7 @@ cluster's conservation invariant, exported as ``conservation_ok``.
 from __future__ import annotations
 
 import hashlib
+import itertools
 import math
 import warnings
 from typing import Optional, Sequence
@@ -46,7 +47,7 @@ from repro.cluster.streams import ClusterStreams
 from repro.core import hocl, netsim, verbs as V
 from repro.core.api import (REPAIR_CAP, _jit_write_phase, bucket_size,
                             pad_to_bucket, run_repair_drain,
-                            write_stats_dict)
+                            write_phase_progress, write_stats_dict)
 from repro.core.netsim import Features, NetConfig, SHERMAN
 from repro.core.tree import TreeConfig, TreeState, bulkload
 from repro.core.write import RepairQueue
@@ -234,7 +235,7 @@ class Cluster:
 
     # -- cluster waves -----------------------------------------------------
     def write_wave(self, keys_by_cs: Sequence, vals_by_cs=None,
-                   is_delete: bool = False, max_phases: int = 8,
+                   is_delete: bool = False,
                    arrivals_by_cs=None, drain: bool = True) -> None:
         """One cluster write wave: every CS's batch, stacked into a single
         ``[n_cs*B]``-lane jitted dispatch per phase, priced phase-by-phase
@@ -291,7 +292,7 @@ class Cluster:
                 route_hits[off:off + k.size] = h[:k.size]
             off += k.size
         phase_sds = []
-        for phase_no in range(max_phases):
+        for phase_no in itertools.count():
             self.state, done, stats, self.repair = _jit_write_phase(
                 self.cfg, self.state, keys_j, vals_j, is_del, active,
                 cs_j, self.repair)
@@ -309,11 +310,8 @@ class Cluster:
                     sd, act_np & (cs_np == i),
                     first_phase=phase_no == 0, st=self.state)
             active = active & ~done
-            if not bool(jnp.any(active)):
+            if not write_phase_progress(act_np, active, stats):
                 break
-        if bool(jnp.any(active)):
-            raise RuntimeError("cluster write wave did not converge; "
-                               "pool exhausted or max_phases too low")
         if drain:
             self.drain_repairs()
         # cross-CS conflict decomposition over the first phase's targets
@@ -367,7 +365,7 @@ class Cluster:
                 off += k.size
         self._maintenance()
 
-    def drain_repairs(self, max_iters: int = 16, sync_every: int = 4):
+    def drain_repairs(self, sync_every: int = 4):
         """Complete the wave's outstanding B-link half-splits (shared
         fixed-capacity queue, fleet scope).  Mirrors
         ``ShermanIndex.drain_repairs``: the jitted step returns the
@@ -380,7 +378,7 @@ class Cluster:
             return
         (self.state, self.repair, n_int, n_root,
          self._repair_backlog) = run_repair_drain(
-            self.cfg, self.state, self.repair, max_iters, sync_every)
+            self.cfg, self.state, self.repair, sync_every)
         self.counters["internal_splits"] += n_int
         self.counters["root_splits"] += n_root
         if self._repair_backlog:

@@ -30,6 +30,7 @@ Shape stability (the jit-cache discipline every driver relies on):
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Optional
 
 import jax
@@ -102,29 +103,53 @@ def _jit_repair(cfg, st, repair):
     return st, repair, ni, nr, pending
 
 
-def run_repair_drain(cfg, state, repair, max_iters: int = 16,
-                     sync_every: int = 4):
+def run_repair_drain(cfg, state, repair, sync_every: int = 4):
     """Drain a repair queue with k-batched host syncs.
 
-    Runs :func:`_jit_repair` steps back-to-back, accumulating the split
-    counters as device scalars, and checks the jitted step's pending
-    count on the host only every ``sync_every`` iterations.  Returns
-    ``(state, repair, n_internal, n_root, backlog)`` — ``backlog`` is
-    the host-side pending count after the drain (0 when it completed).
-    Shared by ``ShermanIndex.drain_repairs`` and the cluster scheduler's
-    wave-scope drain so their sync semantics cannot diverge.
+    Runs :func:`_jit_repair` steps back-to-back and checks the jitted
+    step's pending count and split counters on the host only every
+    ``sync_every`` iterations.  The drain runs until the queue is empty,
+    or until a check finds that the steps since the last one neither
+    shrank the queue nor split a node.  Returns ``(state, repair,
+    n_internal, n_root, backlog)``; ``backlog`` is the host-side pending
+    count after the drain (0 when it completed).  Shared by
+    ``ShermanIndex.drain_repairs`` and the cluster scheduler's wave-scope
+    drain so their sync semantics cannot diverge.
     """
-    ni_acc, nr_acc, pending = [], [], None
-    for it in range(max_iters):
+    n_int = n_root = 0
+    window, last = [], None
+    for it in itertools.count():
         state, repair, ni, nr, pending = _jit_repair(cfg, state, repair)
-        ni_acc.append(ni)
-        nr_acc.append(nr)
+        window.append((ni, nr))
         # one step usually clears a write batch's handful of separators,
         # so check after the first step too, then every sync_every
-        if (it == 0 or (it + 1) % sync_every == 0) and not int(pending):
-            break
-    return (state, repair, sum(int(x) for x in ni_acc),
-            sum(int(x) for x in nr_acc), int(pending))
+        if it and (it + 1) % sync_every:
+            continue
+        ni_w = sum(int(a) for a, _ in window)
+        nr_w = sum(int(b) for _, b in window)
+        n_int, n_root, window = n_int + ni_w, n_root + nr_w, []
+        backlog = int(pending)
+        if not backlog or (last is not None and backlog >= last
+                           and not (ni_w or nr_w)):
+            return state, repair, n_int, n_root, backlog
+        last = backlog
+
+
+def write_phase_progress(before: np.ndarray, active, stats) -> bool:
+    """Should the write loop run another phase?
+
+    Deferred lanes are client retries: they go again for as long as each
+    phase completes a lane or splits a leaf.  A phase that does neither
+    means the node pool has no room left, and the batch fails.  Shared by
+    ``ShermanIndex._write`` and the cluster scheduler's write wave.
+    """
+    left = np.asarray(active)
+    if not left.any():
+        return False
+    if left.sum() == before.sum() and not int(stats.n_leaf_splits):
+        raise RuntimeError("write batch made no progress; "
+                           "node pool exhausted")
+    return True
 
 
 def write_stats_dict(stats: write.WriteStats, active, route_hits,
@@ -261,7 +286,7 @@ class ShermanIndex:
         c["handovers"] += int(stats.handovers)
 
     # -- write ops -------------------------------------------------------
-    def _write(self, keys, vals, is_delete, max_phases: int = 8):
+    def _write(self, keys, vals, is_delete):
         keys = jnp.asarray(keys, jnp.int32)
         n = keys.shape[0]
         if n == 0:
@@ -283,28 +308,26 @@ class ShermanIndex:
         # each client op counts once; lanes resubmitted by later phases
         # are tracked separately so throughput isn't inflated
         self.counters["write_ops"] += n
-        for phase_no in range(max_phases):
+        for phase_no in itertools.count():
             self.state, done, stats, self._repair = _jit_write_phase(
                 self.cfg, self.state, keys, vals, is_del, active, cs,
                 self._repair)
-            self._price_write(stats, np.asarray(active), route_hits)
+            act_np = np.asarray(active)
+            self._price_write(stats, act_np, route_hits)
             if phase_no:
-                self.counters["retried_ops"] += int(np.asarray(active).sum())
+                self.counters["retried_ops"] += int(act_np.sum())
             # invalidation hook: feed this phase's split outputs to the cache
             self.cache.note_splits(int(stats.n_leaf_splits),
                                    int(stats.n_internal_splits),
                                    int(stats.n_root_splits), self.state)
             self._repair_backlog = int(stats.repair_backlog)
             active = active & ~done
-            if not bool(jnp.any(active)):
+            if not write_phase_progress(act_np, active, stats):
                 break
-        if bool(jnp.any(active)):
-            raise RuntimeError("write batch did not converge; "
-                               "pool exhausted or max_phases too low")
         self.drain_repairs()
         self._price_cache_maintenance()
 
-    def drain_repairs(self, max_iters: int = 16, sync_every: int = 4):
+    def drain_repairs(self, sync_every: int = 4):
         """Complete any outstanding B-link half-splits.
 
         The jitted repair step returns the post-step pending count, so
@@ -316,7 +339,7 @@ class ShermanIndex:
             return
         (self.state, self._repair, n_int, n_root,
          self._repair_backlog) = run_repair_drain(
-            self.cfg, self.state, self._repair, max_iters, sync_every)
+            self.cfg, self.state, self._repair, sync_every)
         self.counters["internal_splits"] += n_int
         self.counters["root_splits"] += n_root
         if n_int or n_root:

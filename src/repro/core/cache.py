@@ -25,10 +25,11 @@ Coherence protocol (documented in docs/DESIGN.md §9):
    periodic sweep that re-reads the FNVs of all cached rows and invalidates
    entries whose version moved (a root split forces a full refresh).
 
-The descent and the leaf probe are shape-static JAX; the hot leaf search
-runs through the Pallas kernel in :mod:`repro.kernels.leaf_search.kernel`
-(``interpret`` mode off-TPU, with :mod:`repro.kernels.leaf_search.ref` as
-the pure-jnp fallback).
+The descent and the leaf probe are shape-static JAX; on a TPU the hot leaf
+search runs through the Pallas kernel in
+:mod:`repro.kernels.leaf_search.kernel`.  Elsewhere it runs the pure-jnp
+oracle :mod:`repro.kernels.leaf_search.ref`; CPU tests run the kernel in
+``interpret`` mode against it.
 """
 from __future__ import annotations
 
@@ -55,8 +56,14 @@ class CacheStats(NamedTuple):
 
 
 # --------------------------------------------------------------------------
-# image construction (host side)
+# image construction (rows chosen on the host, gathered on the device)
 # --------------------------------------------------------------------------
+
+@jax.jit
+def _take_rows(arrays: tuple, rows) -> tuple:
+    """Gather ``rows`` of each pool array on the device."""
+    return tuple(a[rows] for a in arrays)
+
 
 def fill_image(cfg: TreeConfig, st: TreeState, levels: Optional[int] = None,
                max_rows: Optional[int] = None) -> tuple[dict, int]:
@@ -87,15 +94,21 @@ def fill_image(cfg: TreeConfig, st: TreeState, levels: Optional[int] = None,
     evicted = max(0, cand.shape[0] - max_rows)
     pad = max_rows - kept.shape[0]
     rows = np.concatenate([kept, np.full(pad, ROW_SENTINEL, np.int32)])
-    safe = np.clip(rows, 0, cfg.n_nodes - 1)
+    # gather the kept rows where the pool lives: only the image, never the
+    # whole [N, F] pool, crosses to the host
+    keys, vals, lv, fnv = _take_rows(
+        (st.keys, st.vals, st.level, st.fnv),
+        np.clip(rows, 0, cfg.n_nodes - 1))
     img = dict(
         rows=jnp.asarray(rows),
-        keys=jnp.asarray(np.asarray(st.keys)[safe]),
-        vals=jnp.asarray(np.asarray(st.vals)[safe]),
-        level=jnp.asarray(np.asarray(st.level)[safe]),
+        keys=keys,
+        vals=vals,
+        level=lv,
         valid=jnp.asarray(rows != ROW_SENTINEL),
-        fnv=jnp.asarray(np.asarray(st.fnv)[safe]),
-        root=jnp.asarray(st.root),
+        fnv=fnv,
+        # a host copy: the write phases donate the tree state, so an alias
+        # of ``st.root`` would be deleted under the image by the next write
+        root=np.int32(st.root),
     )
     return img, evicted
 
@@ -385,8 +398,7 @@ class IndexCache:
         if self._image is None:
             return 0
         safe = np.clip(self._rows, 0, self.cfg.n_nodes - 1)
-        now = np.asarray(st.fnv)[safe]
-        freed = np.asarray(st.free_bit)[safe]
+        now, freed = jax.device_get(_take_rows((st.fnv, st.free_bit), safe))
         changed = self._valid & ((now != self._fnv) | freed)
         n = int(changed.sum())
         if n:
@@ -532,6 +544,7 @@ class IndexCache:
             self.reset()
         else:
             self._image = {k: jnp.asarray(v) for k, v in image.items()}
+            self._image["root"] = np.int32(image["root"])
             self._rows = np.asarray(image["rows"])
             self._filled = self._rows != ROW_SENTINEL
             self._valid = np.asarray(image["valid"]).copy()

@@ -69,7 +69,31 @@ def traverse(cfg: TreeConfig, st: TreeState, qkeys: jax.Array,
 
     final, (path, plevel) = lax.scan(body, node0, None, length=cfg.max_height)
     hops = 1 + jnp.sum((path[1:] != path[:-1]).astype(jnp.int32), axis=0)
-    return TraceB(leaf=final, path=path, path_level=plevel, hops=hops)
+    final, extra = _chase_right(st.fence_hi, st.sibling, qkeys, final)
+    return TraceB(leaf=final, path=path, path_level=plevel,
+                  hops=hops + extra)
+
+
+@jax.jit
+def _chase_right(fence_hi, sibling, qkeys, node):
+    """Finish the B-link chase at the stop level, unbounded.
+
+    The per-step chase is bounded, but a burst of splits whose separators
+    have not reached the parents yet can leave a key several siblings to
+    the right of where the descent ends.  Every read and write must land on
+    the covering node.  Returns ``(node, hops)``.  (Jitted on its own so
+    that eager callers compile the loop once per shape, not per call.)
+    """
+    def beyond(node):
+        return (qkeys >= fence_hi[node]) & (sibling[node] != NULL_PTR)
+
+    def chase(carry):
+        node, n = carry
+        go = beyond(node)
+        return jnp.where(go, sibling[node], node), n + go.astype(jnp.int32)
+
+    return lax.while_loop(lambda c: jnp.any(beyond(c[0])), chase,
+                          (node, jnp.zeros_like(node)))
 
 
 def parent_at_level(trace: TraceB, level: jax.Array | int) -> jax.Array:

@@ -36,28 +36,6 @@ from repro.core.tree import NULL_PTR, TreeConfig, TreeState
 MEM_AXIS = "model"       # the mem pool shards over the TP/model axis
 DATA_AXIS = "data"
 
-# jax.shard_map landed after 0.4.x (older versions expose it under
-# jax.experimental.shard_map) and its replication-check kwarg was renamed
-# check_rep -> check_vma along the way, so probe the signature, not the
-# version.
-def _shard_map_compat():
-    import inspect
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):
-        params = {}
-    for kw in ("check_vma", "check_rep"):
-        if kw in params:
-            return sm, {kw: False}
-    return sm, {}
-
-
-_shard_map, _SHARD_MAP_KW = _shard_map_compat()
-
-
 def tree_pspecs(cfg: TreeConfig) -> TreeState:
     """PartitionSpecs: pool rows over the mem axis, lock tables likewise."""
     row = P(MEM_AXIS)
@@ -161,11 +139,11 @@ def routed_lookup_fn(cfg: TreeConfig, mesh: Mesh, depth: int = 2):
                        fnv=P(), root=P())
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(specs, cache_specs, P(DATA_AXIS)),
         out_specs=RoutedLookupResult(P(DATA_AXIS), P(DATA_AXIS),
                                      P(DATA_AXIS), P(DATA_AXIS)),
-        **_SHARD_MAP_KW)
+        check_vma=False)
     def fn(st_local, cache, qkeys):
         # responses are identical across the mem axis (psum-combined);
         # one copy per data shard survives

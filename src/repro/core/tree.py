@@ -113,25 +113,26 @@ class TreeState(NamedTuple):
     alloc_rr: jax.Array      # []     int32 round-robin MS cursor
 
 
-def empty_state(cfg: TreeConfig) -> TreeState:
+def empty_state(cfg: TreeConfig, xp=jnp) -> TreeState:
+    """An all-unallocated pool; ``xp=np`` builds it on the host."""
     n, f = cfg.n_nodes, cfg.fanout
     return TreeState(
-        keys=jnp.full((n, f), EMPTY_KEY, jnp.int32),
-        vals=jnp.full((n, f), NULL_PTR, jnp.int32),
-        fev=jnp.zeros((n, f), jnp.uint8),
-        rev=jnp.zeros((n, f), jnp.uint8),
-        fnv=jnp.zeros((n,), jnp.uint8),
-        rnv=jnp.zeros((n,), jnp.uint8),
-        level=jnp.full((n,), -1, jnp.int8),
-        fence_lo=jnp.zeros((n,), jnp.int32),
-        fence_hi=jnp.zeros((n,), jnp.int32),
-        sibling=jnp.full((n,), NULL_PTR, jnp.int32),
-        free_bit=jnp.zeros((n,), bool),
-        glt=jnp.zeros((cfg.n_ms, cfg.n_locks_per_ms), jnp.uint16),
-        root=jnp.int32(0),
-        height=jnp.int32(0),
-        alloc_next=jnp.zeros((cfg.n_ms,), jnp.int32),
-        alloc_rr=jnp.int32(0),
+        keys=xp.full((n, f), EMPTY_KEY, xp.int32),
+        vals=xp.full((n, f), NULL_PTR, xp.int32),
+        fev=xp.zeros((n, f), xp.uint8),
+        rev=xp.zeros((n, f), xp.uint8),
+        fnv=xp.zeros((n,), xp.uint8),
+        rnv=xp.zeros((n,), xp.uint8),
+        level=xp.full((n,), -1, xp.int8),
+        fence_lo=xp.zeros((n,), xp.int32),
+        fence_hi=xp.zeros((n,), xp.int32),
+        sibling=xp.full((n,), NULL_PTR, xp.int32),
+        free_bit=xp.zeros((n,), bool),
+        glt=xp.zeros((cfg.n_ms, cfg.n_locks_per_ms), xp.uint16),
+        root=xp.int32(0),
+        height=xp.int32(0),
+        alloc_next=xp.zeros((cfg.n_ms,), xp.int32),
+        alloc_rr=xp.int32(0),
     )
 
 
@@ -142,6 +143,10 @@ def bulkload(cfg: TreeConfig, keys: np.ndarray, vals: np.ndarray,
     Host-side (numpy) setup, mirroring the paper's bulkload of 1B entries 80%
     full before each benchmark.  Leaves are written *sorted* here — unsortedness
     only arises from subsequent inserts, which is also true of the paper.
+    Nodes are allocated leaves first, then each internal level bottom-up,
+    by the two-stage allocator: round-robin over the MSs, a bump pointer
+    within each.  On an empty pool that puts the t-th node on MS
+    ``t % n_ms`` at local row ``t // n_ms``.
     """
     keys = np.asarray(keys, np.int32)
     vals = np.asarray(vals, np.int32)
@@ -153,85 +158,47 @@ def bulkload(cfg: TreeConfig, keys: np.ndarray, vals: np.ndarray,
 
     f = cfg.fanout
     per_leaf = max(1, min(f, int(round(f * fill))))
-    n, _ = cfg.n_nodes, cfg.fanout
-
-    st = jax.tree_util.tree_map(np.asarray, empty_state(cfg))
-    st = TreeState(*[np.array(x) for x in st])
-
-    next_row = np.zeros(cfg.n_ms, np.int64)
-    rr = [0]
-
-    def alloc() -> int:
-        # two-stage allocator: round-robin MS choice + per-MS bump pointer
-        for _ in range(cfg.n_ms):
-            ms = rr[0] % cfg.n_ms
-            rr[0] += 1
-            if next_row[ms] < cfg.alloc_cap:
-                row = ms * cfg.nodes_per_ms + int(next_row[ms])
-                next_row[ms] += 1
-                return row
+    n_leaves = max(1, -(-keys.size // per_leaf))
+    widths = [n_leaves]                      # nodes per level, leaves up
+    while widths[-1] > 1:
+        widths.append(-(-widths[-1] // f))
+    total = sum(widths)
+    if total > cfg.n_ms * cfg.alloc_cap:
         raise RuntimeError("node pool exhausted during bulkload")
+    t = np.arange(total, dtype=np.int64)
+    rows = ((t % cfg.n_ms) * cfg.nodes_per_ms + t // cfg.n_ms
+            ).astype(np.int32)
+    st = empty_state(cfg, np)
 
-    # ---- build leaf level ----
-    level_nodes: list[int] = []     # node ids of current level, left→right
-    level_keys: list[int] = []      # lower fence of each node
-    if keys.size == 0:
-        nid = alloc()
-        st.level[nid] = 0
-        st.fence_lo[nid], st.fence_hi[nid] = KEY_MIN, KEY_MAX
-        level_nodes, level_keys = [nid], [KEY_MIN]
-    else:
-        starts = list(range(0, keys.size, per_leaf))
-        for j, s in enumerate(starts):
-            chunk = slice(s, min(s + per_leaf, keys.size))
-            nid = alloc()
-            cnt = keys[chunk].size
-            st.keys[nid, :cnt] = keys[chunk]
-            st.vals[nid, :cnt] = vals[chunk]
-            st.level[nid] = 0
-            st.fence_lo[nid] = KEY_MIN if j == 0 else int(keys[s])
-            st.fence_hi[nid] = (KEY_MAX if j == len(starts) - 1
-                                else int(keys[starts[j + 1]]))
-            if level_nodes:
-                st.sibling[level_nodes[-1]] = nid
-            level_nodes.append(nid)
-            level_keys.append(int(st.fence_lo[nid]))
+    # ---- leaf level: consecutive per_leaf-key chunks ----
+    nid = rows[:n_leaves]
+    pad = n_leaves * per_leaf - keys.size
+    st.keys[nid, :per_leaf] = np.pad(keys, (0, pad), constant_values=-1
+                                     ).reshape(n_leaves, per_leaf)
+    st.vals[nid, :per_leaf] = np.pad(vals, (0, pad), constant_values=-1
+                                     ).reshape(n_leaves, per_leaf)
+    lo = np.full(n_leaves, KEY_MIN, np.int64)
+    lo[1:] = keys[per_leaf::per_leaf]
+    off = n_leaves
+    # ---- internal levels bottom-up: groups of f consecutive nodes ----
+    for lvl, width in enumerate(widths):
+        if lvl:
+            nid = rows[off:off + width]
+            child, child_lo = rows[off - widths[lvl - 1]:off], lo
+            grp = np.arange(child.size) // f
+            slot = np.arange(child.size) % f
+            st.keys[nid[grp], slot] = child_lo
+            st.vals[nid[grp], slot] = child
+            lo = child_lo[::f]
+            off += width
+        st.level[nid] = lvl
+        st.fence_lo[nid] = lo
+        st.fence_hi[nid] = np.append(lo[1:], KEY_MAX)
+        st.sibling[nid[:-1]] = nid[1:]
 
-    # ---- build internal levels bottom-up ----
-    lvl = 0
-    while len(level_nodes) > 1:
-        lvl += 1
-        parents, parent_keys = [], []
-        for s in range(0, len(level_nodes), f):
-            group = level_nodes[s:s + f]
-            gkeys = level_keys[s:s + f]
-            nid = alloc()
-            st.keys[nid, :len(group)] = gkeys
-            st.vals[nid, :len(group)] = group
-            st.level[nid] = lvl
-            st.fence_lo[nid] = gkeys[0] if s else KEY_MIN
-            parents.append(nid)
-            parent_keys.append(KEY_MIN if s == 0 else gkeys[0])
-        for j, nid in enumerate(parents):
-            st.fence_hi[nid] = (KEY_MAX if j == len(parents) - 1
-                                else parent_keys[j + 1])
-            if j + 1 < len(parents):
-                st.sibling[nid] = parents[j + 1]
-        # first separator of each internal node must equal its lower fence
-        for nid in parents:
-            st.keys[nid, 0] = st.fence_lo[nid]
-        level_nodes, level_keys = parents, parent_keys
-
-    root = level_nodes[0]
-    out = TreeState(
-        keys=jnp.asarray(st.keys), vals=jnp.asarray(st.vals),
-        fev=jnp.asarray(st.fev), rev=jnp.asarray(st.rev),
-        fnv=jnp.asarray(st.fnv), rnv=jnp.asarray(st.rnv),
-        level=jnp.asarray(st.level),
-        fence_lo=jnp.asarray(st.fence_lo), fence_hi=jnp.asarray(st.fence_hi),
-        sibling=jnp.asarray(st.sibling), free_bit=jnp.asarray(st.free_bit),
-        glt=jnp.asarray(st.glt),
-        root=jnp.int32(root), height=jnp.int32(lvl + 1),
-        alloc_next=jnp.asarray(next_row, jnp.int32), alloc_rr=jnp.int32(rr[0]),
-    )
-    return out
+    return TreeState(
+        *[jnp.asarray(a) for a in st[:12]],
+        root=jnp.int32(rows[total - 1]), height=jnp.int32(len(widths)),
+        alloc_next=jnp.asarray(np.bincount(t % cfg.n_ms,
+                                           minlength=cfg.n_ms), jnp.int32),
+        alloc_rr=jnp.int32(total))

@@ -4,30 +4,28 @@ Every jitted entry point in the hot path (:mod:`repro.core.api`) pads its
 batch to a power-of-two bucket and keeps the repair queue at a fixed
 capacity, so a steady-state workload must not trigger *any* fresh XLA
 compilation after its warmup batch.  This module counts compilations so
-benchmarks (``benchmarks/run.py --only throughput``) can report them and
-CI / tests (``tests/test_throughput.py``) can regress on them.
+benchmarks (``benchmarks/run.py --only throughput``), ``chip_smoke.py``
+and tests (``tests/test_throughput.py``) can report and regress on them.
 
-The count hooks ``MeshComputation.compile`` — the single funnel every
-XLA build passes through on the jax pinned in this container (0.4.x);
-jit-cache hits never reach it, so the tally is *distinct compilations*,
-not dispatches.  (``jax.monitoring`` events were considered and
-rejected: on this version they fire per compile *request* — cache hits
-included — and listeners cannot be unregistered.)  If a future jax
-moves the internals, :func:`count_compiles` degrades to ``available =
-False`` / ``count == -1`` rather than miscounting, and the consumers
-skip their assertions.
+The count hooks ``MeshComputation.compile``, the one funnel every XLA
+build passes through; jit-cache hits never reach it, so the tally is
+*distinct compilations*, not dispatches.  (``jax.monitoring`` events fire
+per compile *request*, cache hits included, and listeners cannot be
+unregistered.)  Should a JAX upgrade move that funnel, the import below
+fails loudly.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 
+from jax._src.interpreters import pxla
+
 
 @dataclasses.dataclass
 class CompileStats:
     """Mutable compile tally, readable while the context is active."""
     count: int = 0
-    available: bool = True
 
 
 @contextlib.contextmanager
@@ -39,14 +37,7 @@ def count_compiles():
     >>> stats.count
     """
     stats = CompileStats()
-    try:
-        from jax._src.interpreters import pxla
-        orig = pxla.MeshComputation.compile
-    except Exception:
-        stats.available = False
-        stats.count = -1
-        yield stats
-        return
+    orig = pxla.MeshComputation.compile
 
     def counted(self, *a, **kw):
         stats.count += 1

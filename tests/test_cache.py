@@ -291,6 +291,30 @@ def test_root_split_forces_refresh():
     assert idx.cache.counters.fills > fills0      # image was rebuilt
 
 
+def test_donated_state_cycle_matches_oracle():
+    """write -> cached lookup -> write -> cached lookup, cache on.  The
+    write phases donate the tree state to XLA, so nothing the cache keeps
+    across a write may alias it.  The tree starts as one leaf, so the
+    writes split the root."""
+    cfg = TreeConfig(n_ms=2, nodes_per_ms=1024, fanout=4,
+                     n_locks_per_ms=512, max_height=7, n_cs=2)
+    idx = ShermanIndex.empty(cfg)
+    oracle = OracleIndex()
+    keys = np.random.default_rng(11).permutation(5_000)[:600]
+    keys = keys.astype(np.int32)
+    for step, part in enumerate(np.array_split(keys, 4)):
+        vals = (part * 3 + step).astype(np.int32)
+        idx.insert(part, vals)
+        oracle.insert_batch(part, vals)
+        probe = keys[:150 * step + 200]        # some not inserted yet
+        got, found = idx.lookup(probe)
+        for k, g, f in zip(probe, got, found):
+            want = oracle.lookup(int(k))
+            assert (want is None and not f) or (f and g == want), (k, g)
+    assert idx.counters["root_splits"] >= 1
+    assert idx.counters["cache_hits"] > 0
+
+
 # -- kernel parity ---------------------------------------------------------
 
 def test_cached_lookup_kernel_parity():
@@ -299,7 +323,7 @@ def test_cached_lookup_kernel_parity():
     import jax.numpy as jnp
     idx = _fresh(records=3_000)
     img, _ = fill_image(CFG, idx.state)
-    for n in (100, 256, 300):
+    for n in (100, 256, 300, 1024):
         q = jnp.asarray(_ranks(0, n))
         r_ref, s_ref = cached_lookup(CFG, idx.state, img, q,
                                      kernel_mode="ref")

@@ -34,7 +34,8 @@ def test_flash_attention(b, h, kv, s, hd, causal, dtype):
 
 
 @pytest.mark.parametrize("b,f,bt", [(256, 8, 64), (512, 16, 128),
-                                    (128, 32, 128), (256, 64, 256)])
+                                    (128, 32, 128), (256, 64, 256),
+                                    (4096, 16, 256)])
 def test_leaf_search(b, f, bt):
     keys = np.stack([RNG.choice(9_000, f, replace=False)
                      for _ in range(b)]).astype(np.int32)

@@ -1,0 +1,9 @@
+"""Put the benchmark's modules and the program on the path.  These tests
+run on the CPU (``JAX_PLATFORMS=cpu``): ``python -m pytest bench/tests``."""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for p in (BENCH, os.path.join(os.path.dirname(BENCH), "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)

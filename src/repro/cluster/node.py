@@ -38,6 +38,7 @@ from repro.core.api import (_jit_lookup, _jit_range, _jit_range_cached,
                             bucket_size, pad_to_bucket)
 from repro.core.cache import IndexCache
 from repro.core.tree import TreeConfig, TreeState
+from repro.obs.host import fetch, span
 
 
 class ClusterNode:
@@ -115,38 +116,40 @@ class ClusterNode:
         Returns ``(values, found, stats)`` where ``stats`` is the read
         trace's input dict (per-lane remote reads + target leaves, padded
         to the dispatch bucket with an ``active`` prefix mask)."""
-        keys = jnp.asarray(keys, jnp.int32)
-        n = keys.shape[0]
-        m = bucket_size(n)
-        kp = pad_to_bucket(keys, m)
-        active = np.arange(m) < n
-        c = self.counters
-        if self.cache.enabled:
-            res, cst = self.cache.lookup(st, kp, n_valid=n)
-            hit, stale = cst["hit"][:n], cst["stale"][:n]
-            c["cache_hits"] += int((hit & ~stale).sum())
-            c["cache_misses"] += int((~hit).sum())
-            c["cache_stale"] += int(stale.sum())
-            reads = cst["remote_reads"]
-            n_reads = int(reads[:n].sum())
-            sd = dict(active=active,
-                      cache_hit=cst["hit"] & ~cst["stale"],
-                      remote_reads=reads,
-                      leaf=np.asarray(res.leaf),
-                      height=int(st.height))
-        else:
-            res = _jit_lookup(self.cfg, st, kp)
-            c["cache_misses"] += n
-            n_reads = n * max(int(st.height), 1)
-            sd = dict(active=active,
-                      cache_hit=np.zeros(m, bool),
-                      leaf=np.asarray(res.leaf),
-                      height=int(st.height))
-        c["read_ops"] += n
-        c["ops"] += n
-        c["lookup_ops"] += n
-        c["lookup_reads"] += n_reads
-        return np.asarray(res.value)[:n], np.asarray(res.found)[:n], sd
+        with span("sherman.cs_lookup", cs=self.cs_id):
+            keys = jnp.asarray(keys, jnp.int32)
+            n = keys.shape[0]
+            m = bucket_size(n)
+            kp = pad_to_bucket(keys, m)
+            active = np.arange(m) < n
+            c = self.counters
+            if self.cache.enabled:
+                res, cst = self.cache.lookup(st, kp, n_valid=n)
+                hit, stale = cst["hit"][:n], cst["stale"][:n]
+                c["cache_hits"] += int((hit & ~stale).sum())
+                c["cache_misses"] += int((~hit).sum())
+                c["cache_stale"] += int(stale.sum())
+                reads = cst["remote_reads"]
+                n_reads = int(reads[:n].sum())
+                sd = dict(active=active,
+                          cache_hit=cst["hit"] & ~cst["stale"],
+                          remote_reads=reads,
+                          leaf=fetch(res.leaf, "lookup.leaf"),
+                          height=int(fetch(st.height, "height")))
+            else:
+                res = _jit_lookup(self.cfg, st, kp)
+                c["cache_misses"] += n
+                n_reads = n * max(int(fetch(st.height, "height")), 1)
+                sd = dict(active=active,
+                          cache_hit=np.zeros(m, bool),
+                          leaf=fetch(res.leaf, "lookup.leaf"),
+                          height=int(fetch(st.height, "height")))
+            c["read_ops"] += n
+            c["ops"] += n
+            c["lookup_ops"] += n
+            c["lookup_reads"] += n_reads
+            return (fetch(res.value, "lookup.value")[:n],
+                    fetch(res.found, "lookup.found")[:n], sd)
 
     def scan_batch(self, st: TreeState, lo, count: int,
                    max_leaves: Optional[int] = None):
@@ -160,21 +163,22 @@ class ClusterNode:
         if self.cache.enabled:
             res = _jit_range_cached(self.cfg, st, lo_p, count, max_leaves,
                                     self.cache.image(st))
-            hits = np.asarray(res.start_hit)
+            hits = fetch(res.start_hit, "scan.start_hit")
             self.cache.note_hits(hits[:n])
         else:
             res = _jit_range(self.cfg, st, lo_p, count, max_leaves)
             hits = np.zeros(m, bool)
-        n_leaves = np.asarray(res.leaves_read)
+        n_leaves = fetch(res.leaves_read, "scan.leaves_read")
         sd = dict(active=np.arange(m) < n, cache_hit=hits,
                   retries=np.maximum(n_leaves - 1, 0),
-                  leaf=np.asarray(res.start_leaf), scan=True,
-                  height=int(st.height))
+                  leaf=fetch(res.start_leaf, "scan.start_leaf"), scan=True,
+                  height=int(fetch(st.height, "height")))
         c = self.counters
         c["read_ops"] += n
         c["ops"] += n
-        return (np.asarray(res.keys)[:n], np.asarray(res.vals)[:n],
-                np.asarray(res.n)[:n]), sd
+        return (fetch(res.keys, "scan.keys")[:n],
+                fetch(res.vals, "scan.vals")[:n],
+                fetch(res.n, "scan.n")[:n]), sd
 
     # -- coherence tick ----------------------------------------------------
     def end_round(self, st: TreeState) -> None:

@@ -33,6 +33,7 @@ cluster's conservation invariant, exported as ``conservation_ok``.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import itertools
 import math
@@ -51,10 +52,23 @@ from repro.core.api import (REPAIR_CAP, _jit_write_phase, bucket_size,
 from repro.core.netsim import Features, NetConfig, SHERMAN
 from repro.core.tree import TreeConfig, TreeState, bulkload
 from repro.core.write import RepairQueue
+from repro.obs.host import counting, fetch, span
 from repro.workloads.keygen import scramble
 from repro.workloads.spec import OP_KINDS, WorkloadSpec
 
 VAL_MASK = (1 << 30) - 1
+
+
+def _wave(name: str):
+    """Run a Cluster wave method inside span ``name``, counting its
+    device-to-host reads into the cluster's ``host_fetches``."""
+    def wrap(method):
+        @functools.wraps(method)
+        def run(self, *args, **kwargs):
+            with span(name), counting(self.counters):
+                return method(self, *args, **kwargs)
+        return run
+    return wrap
 
 
 class Cluster:
@@ -95,6 +109,7 @@ class Cluster:
             "cas_msgs": 0, "sim_time_s": 0.0, "merged_waves": 0,
             "rounds": 0, "cross_cs_conflicts": 0,
             "stacked_phases": 0, "internal_splits": 0, "root_splits": 0,
+            "host_fetches": 0,
         }
         self.latencies_write: list[np.ndarray] = []
         self.latencies_read: list[np.ndarray] = []
@@ -174,9 +189,11 @@ class Cluster:
                 # closed loop: place this wave's relative timeline at the
                 # accumulated sim time (open loop is already absolute)
                 rec.sync_cursor(self.counters["sim_time_s"])
-        sim, merged = netsim.price_merged_phase(
-            [t for _, t in tagged], self.features, self.net, self.cfg,
-            clock=self.clock, recorder=rec)
+        with span("sherman.price", kind=kind,
+                  verbs=sum(t.n_verbs for _, t in tagged)):
+            sim, merged = netsim.price_merged_phase(
+                [t for _, t in tagged], self.features, self.net, self.cfg,
+                clock=self.clock, recorder=rec)
         if self.trace_log is not None:
             self.trace_log.append(self._trace_digest(kind, merged))
         c = self.counters
@@ -219,21 +236,24 @@ class Cluster:
         In open-loop mode the background verbs are released at the
         current horizon — maintenance generated by a wave cannot start
         before the wave was admitted."""
-        tagged = []
-        for i, node in enumerate(self.nodes):
-            nr, sr = node.take_maintenance()
-            if nr or sr:
-                tagged.append((i, V.maintenance_trace(
-                    nr, sr, self.cfg.n_ms, self.cfg.node_bytes,
-                    self.net.small_io_bytes,
-                    rows_ms=node.cache.rows_ms())))
-        if self.clock is not None and tagged:
-            t0 = self.counters["sim_time_s"]
-            tagged = [(i, V.shift_release(t, np.zeros(t.n_lanes), t0))
-                      for i, t in tagged]
-        self._simulate_merged(tagged, "maint")
+        with span("sherman.maintenance"):
+            tagged = []
+            with span("sherman.trace_build", kind="maint"):
+                for i, node in enumerate(self.nodes):
+                    nr, sr = node.take_maintenance()
+                    if nr or sr:
+                        tagged.append((i, V.maintenance_trace(
+                            nr, sr, self.cfg.n_ms, self.cfg.node_bytes,
+                            self.net.small_io_bytes,
+                            rows_ms=node.cache.rows_ms())))
+            if self.clock is not None and tagged:
+                t0 = self.counters["sim_time_s"]
+                tagged = [(i, V.shift_release(t, np.zeros(t.n_lanes), t0))
+                          for i, t in tagged]
+            self._simulate_merged(tagged, "maint")
 
     # -- cluster waves -----------------------------------------------------
+    @_wave("sherman.write_wave")
     def write_wave(self, keys_by_cs: Sequence, vals_by_cs=None,
                    is_delete: bool = False,
                    arrivals_by_cs=None, drain: bool = True) -> None:
@@ -282,45 +302,53 @@ class Cluster:
         # work stays O(total lanes) instead of O(n_cs * total lanes)
         route_hits = np.zeros(m, bool)
         off = 0
-        for i, k, _ in segs:
-            node = self.nodes[i]
-            node.counters["write_ops"] += k.size
-            node.counters["ops"] += k.size
-            if node.cache.enabled:
-                kp = pad_to_bucket(jnp.asarray(k), bucket_size(k.size))
-                h = node.cache.route_hits(self.state, kp, n_valid=k.size)
-                route_hits[off:off + k.size] = h[:k.size]
-            off += k.size
+        with span("sherman.write.route"):
+            for i, k, _ in segs:
+                node = self.nodes[i]
+                node.counters["write_ops"] += k.size
+                node.counters["ops"] += k.size
+                if node.cache.enabled:
+                    kp = pad_to_bucket(jnp.asarray(k), bucket_size(k.size))
+                    h = node.cache.route_hits(self.state, kp,
+                                              n_valid=k.size)
+                    route_hits[off:off + k.size] = h[:k.size]
+                off += k.size
         phase_sds = []
         for phase_no in itertools.count():
-            self.state, done, stats, self.repair = _jit_write_phase(
-                self.cfg, self.state, keys_j, vals_j, is_del, active,
-                cs_j, self.repair)
-            act_np = np.asarray(active)
-            sd = write_stats_dict(stats, act_np, route_hits,
-                                  int(self.state.height))
-            phase_sds.append(sd)
-            c = self.counters
-            c["stacked_phases"] += 1
-            c["internal_splits"] += int(stats.n_internal_splits)
-            c["root_splits"] += int(stats.n_root_splits)
-            self._repair_backlog = int(stats.repair_backlog)
-            for i, _, _ in segs:
-                self.nodes[i].note_write_phase(
-                    sd, act_np & (cs_np == i),
-                    first_phase=phase_no == 0, st=self.state)
-            active = active & ~done
-            if not write_phase_progress(act_np, active, stats):
-                break
+            with span("sherman.write.phase", phase=phase_no):
+                self.state, done, stats, self.repair = _jit_write_phase(
+                    self.cfg, self.state, keys_j, vals_j, is_del, active,
+                    cs_j, self.repair)
+                act_np = fetch(active, "write.active")
+                sd = write_stats_dict(stats, act_np, route_hits,
+                                      int(fetch(self.state.height,
+                                                "height")))
+                phase_sds.append(sd)
+                c = self.counters
+                c["stacked_phases"] += 1
+                c["internal_splits"] += int(fetch(
+                    stats.n_internal_splits, "stats.n_internal_splits"))
+                c["root_splits"] += int(fetch(stats.n_root_splits,
+                                              "stats.n_root_splits"))
+                self._repair_backlog = int(fetch(stats.repair_backlog,
+                                                 "stats.repair_backlog"))
+                for i, _, _ in segs:
+                    self.nodes[i].note_write_phase(
+                        sd, act_np & (cs_np == i),
+                        first_phase=phase_no == 0, st=self.state)
+                active = active & ~done
+                if not write_phase_progress(act_np, active, stats):
+                    break
         if drain:
             self.drain_repairs()
         # cross-CS conflict decomposition over the first phase's targets
         sd0 = phase_sds[0]
-        leaves = [np.asarray(sd0["leaf"])[sd0["active"] & (cs_np == i)]
-                  for i, _, _ in segs]
-        if sum(1 for lv in leaves if lv.size) > 1:
-            self.counters["cross_cs_conflicts"] += \
-                hocl.cross_cs_contention(leaves)["contended_nodes"]
+        with span("sherman.trace_build", kind="write"):
+            leaves = [np.asarray(sd0["leaf"])[sd0["active"] & (cs_np == i)]
+                      for i, _, _ in segs]
+            if sum(1 for lv in leaves if lv.size) > 1:
+                self.counters["cross_cs_conflicts"] += \
+                    hocl.cross_cs_contention(leaves)["contended_nodes"]
         # performance plane: split each phase back into per-CS traces
         open_mode = self.clock is not None
         if open_mode:
@@ -336,15 +364,16 @@ class Cluster:
             op_queue = np.zeros(m)         # per-op NIC/atomic queueing
             release = arr_full.copy()      # next phase's release floor
         for sd in phase_sds:
-            masks = {i: sd["active"] & (cs_np == i) for i, _, _ in segs}
-            tagged = []
-            for i, _, _ in segs:
-                t = netsim.transformed_write_trace(
-                    dict(sd, active=masks[i]), self.features, self.net,
-                    self.cfg)
-                if open_mode and t.n_verbs:
-                    t = V.shift_release(t, release[masks[i]])
-                tagged.append((i, t))
+            with span("sherman.trace_build", kind="write"):
+                masks = {i: sd["active"] & (cs_np == i) for i, _, _ in segs}
+                tagged = []
+                for i, _, _ in segs:
+                    t = netsim.transformed_write_trace(
+                        dict(sd, active=masks[i]), self.features, self.net,
+                        self.cfg)
+                    if open_mode and t.n_verbs:
+                        t = V.shift_release(t, release[masks[i]])
+                    tagged.append((i, t))
             sim, kept = self._simulate_merged(tagged, "write")
             if open_mode and sim is not None:
                 lanes = {i: t.n_lanes for i, t in tagged if t.n_verbs}
@@ -376,9 +405,10 @@ class Cluster:
         coherence protocol)."""
         if not self._repair_backlog:
             return
-        (self.state, self.repair, n_int, n_root,
-         self._repair_backlog) = run_repair_drain(
-            self.cfg, self.state, self.repair, sync_every)
+        with span("sherman.write.drain"):
+            (self.state, self.repair, n_int, n_root,
+             self._repair_backlog) = run_repair_drain(
+                self.cfg, self.state, self.repair, sync_every)
         self.counters["internal_splits"] += n_int
         self.counters["root_splits"] += n_root
         if self._repair_backlog:
@@ -397,6 +427,7 @@ class Cluster:
             shifted.append((i, V.shift_release(t, a)))
         return shifted, arrs
 
+    @_wave("sherman.lookup_wave")
     def lookup_wave(self, keys_by_cs: Sequence,
                     arrivals_by_cs=None) -> list:
         """One cluster lookup wave; returns ``(values, found)`` per CS."""
@@ -407,13 +438,16 @@ class Cluster:
                 out.append((np.zeros(0, np.int32), np.zeros(0, bool)))
                 continue
             vals, found, sd = node.lookup_batch(self.state, keys)
-            tagged.append((i, netsim.read_trace_from_stats(sd, self.cfg)))
+            with span("sherman.trace_build", kind="read"):
+                tagged.append((i, netsim.read_trace_from_stats(sd,
+                                                               self.cfg)))
             out.append((vals, found))
         tagged, arrs = self._shift_reads(tagged, arrivals_by_cs)
         self._simulate_merged(tagged, "read", arrivals=arrs)
         self._maintenance()
         return out
 
+    @_wave("sherman.scan_wave")
     def scan_wave(self, lo_by_cs: Sequence, count: int,
                   max_leaves: Optional[int] = None,
                   arrivals_by_cs=None) -> list:
@@ -425,13 +459,16 @@ class Cluster:
                 out.append(None)
                 continue
             res, sd = node.scan_batch(self.state, lo, count, max_leaves)
-            tagged.append((i, netsim.read_trace_from_stats(sd, self.cfg)))
+            with span("sherman.trace_build", kind="scan"):
+                tagged.append((i, netsim.read_trace_from_stats(sd,
+                                                               self.cfg)))
             out.append(res)
         tagged, arrs = self._shift_reads(tagged, arrivals_by_cs)
         self._simulate_merged(tagged, "read", arrivals=arrs)
         self._maintenance()
         return out
 
+    @_wave("sherman.end_round")
     def end_round(self) -> None:
         """Close one scheduler tick: per-CS coherence sweeps, then price
         any maintenance they generated."""
@@ -471,6 +508,13 @@ class Cluster:
             out[k] = nt[k]          # `phases` = per-CS sum, as pre-PR-5
         for k in ("internal_splits", "root_splits"):
             out[k] = nt[k] + self.counters[k]   # + wave-scope repairs
+        # the CS caches' upkeep: image fills and version sweeps, and the
+        # reads each priced
+        caches = [n.cache.counters for n in self.nodes]
+        out["cache_fills"] = sum(c.fills for c in caches)
+        out["cache_sweeps"] = sum(c.sync_sweeps for c in caches)
+        out["maint_fill_reads"] = sum(c.fill_reads for c in caches)
+        out["maint_sync_reads"] = sum(c.sync_reads for c in caches)
         return out
 
     def throughput_mops(self) -> float:

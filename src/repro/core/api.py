@@ -43,6 +43,7 @@ from repro.core.netsim import FG_PLUS, SHERMAN, Features, NetConfig
 from repro.core.ref import OracleIndex
 from repro.core.tree import TreeConfig, TreeState, bulkload, empty_state
 from repro.core.write import RepairQueue
+from repro.obs.host import fetch
 
 __all__ = ["ShermanIndex", "TreeConfig", "Features", "FG_PLUS", "SHERMAN",
            "OracleIndex", "IndexCache", "REPAIR_CAP", "bucket_size",
@@ -125,10 +126,10 @@ def run_repair_drain(cfg, state, repair, sync_every: int = 4):
         # so check after the first step too, then every sync_every
         if it and (it + 1) % sync_every:
             continue
-        ni_w = sum(int(a) for a, _ in window)
-        nr_w = sum(int(b) for _, b in window)
+        ni_w = sum(int(fetch(a, "repair.n_internal")) for a, _ in window)
+        nr_w = sum(int(fetch(b, "repair.n_root")) for _, b in window)
         n_int, n_root, window = n_int + ni_w, n_root + nr_w, []
-        backlog = int(pending)
+        backlog = int(fetch(pending, "repair.pending"))
         if not backlog or (last is not None and backlog >= last
                            and not (ni_w or nr_w)):
             return state, repair, n_int, n_root, backlog
@@ -143,10 +144,11 @@ def write_phase_progress(before: np.ndarray, active, stats) -> bool:
     means the node pool has no room left, and the batch fails.  Shared by
     ``ShermanIndex._write`` and the cluster scheduler's write wave.
     """
-    left = np.asarray(active)
+    left = fetch(active, "write.active")
     if not left.any():
         return False
-    if left.sum() == before.sum() and not int(stats.n_leaf_splits):
+    if left.sum() == before.sum() and \
+            not int(fetch(stats.n_leaf_splits, "stats.n_leaf_splits")):
         raise RuntimeError("write batch made no progress; "
                            "node pool exhausted")
     return True
@@ -159,19 +161,21 @@ def write_stats_dict(stats: write.WriteStats, active, route_hits,
     Shared with the trace-conservation tests so the two stay in sync."""
     return dict(
         active=np.asarray(active),
-        leaf=np.asarray(stats.leaf),
-        local_rank=np.asarray(stats.local_rank),
-        node_rank=np.asarray(stats.node_rank),
-        node_size=np.asarray(stats.node_size),
-        cycle_head=np.asarray(stats.cycle_head),
-        chain_end=np.asarray(stats.chain_end),
-        split_lane=np.asarray(stats.split_mask),
-        split_same_ms=np.asarray(stats.split_same_ms),
-        split_new_row=np.asarray(stats.split_new_row),
+        leaf=fetch(stats.leaf, "stats.leaf"),
+        local_rank=fetch(stats.local_rank, "stats.local_rank"),
+        node_rank=fetch(stats.node_rank, "stats.node_rank"),
+        node_size=fetch(stats.node_size, "stats.node_size"),
+        cycle_head=fetch(stats.cycle_head, "stats.cycle_head"),
+        chain_end=fetch(stats.chain_end, "stats.chain_end"),
+        split_lane=fetch(stats.split_mask, "stats.split_mask"),
+        split_same_ms=fetch(stats.split_same_ms, "stats.split_same_ms"),
+        split_new_row=fetch(stats.split_new_row, "stats.split_new_row"),
         cache_hit=np.asarray(route_hits),
         height=int(height),
-        hocl_remote_cas=int(stats.hocl_remote_cas),
-        flat_remote_cas=int(stats.flat_remote_cas),
+        hocl_remote_cas=int(fetch(stats.hocl_remote_cas,
+                                  "stats.hocl_remote_cas")),
+        flat_remote_cas=int(fetch(stats.flat_remote_cas,
+                                  "stats.flat_remote_cas")),
     )
 
 

@@ -44,6 +44,7 @@ from jax import lax
 
 from repro.core.ops import LookupResult, traverse
 from repro.core.tree import EMPTY_KEY, NULL_PTR, TreeConfig, TreeState
+from repro.obs.host import fetch, span
 
 ROW_SENTINEL = np.int32(2**31 - 1)     # "no row" padding in the sorted image
 
@@ -78,11 +79,11 @@ def fill_image(cfg: TreeConfig, st: TreeState, levels: Optional[int] = None,
     levels are always cached and level-1 nodes are the first evicted when
     ``max_rows`` is short (paper §4.2.3's two cache types).
     """
-    height = int(st.height)
+    height = int(fetch(st.height, "fill.height"))
     if levels is None:
         levels = max(0, height - 1)          # every internal level
-    level = np.asarray(st.level)
-    free = np.asarray(st.free_bit)
+    level = fetch(st.level, "fill.level")
+    free = fetch(st.free_bit, "fill.free_bit")
     lo_level = max(1, height - levels)
     cand = np.nonzero((level >= lo_level) & ~free)[0].astype(np.int32)
     # top-down: higher levels first, row order within a level
@@ -108,7 +109,7 @@ def fill_image(cfg: TreeConfig, st: TreeState, levels: Optional[int] = None,
         fnv=fnv,
         # a host copy: the write phases donate the tree state, so an alias
         # of ``st.root`` would be deleted under the image by the next write
-        root=np.int32(st.root),
+        root=np.int32(fetch(st.root, "fill.root")),
     )
     return img, evicted
 
@@ -211,25 +212,29 @@ def cached_lookup(cfg: TreeConfig, st: TreeState, image: dict,
     ``CacheStats.remote_reads`` counts what a real CS would have issued, and
     is what netsim prices.
     """
-    leaf0, hit, depth = descend_image(image, qkeys, cfg.max_height)
-    leaf = jnp.where(hit, leaf0, 0)
+    with jax.named_scope("descend"):
+        leaf0, hit, depth = descend_image(image, qkeys, cfg.max_height)
+        leaf = jnp.where(hit, leaf0, 0)
 
     # --- the single remote leaf read, validated by fences + B-link chase ---
-    chased = jnp.zeros_like(leaf)
-    for _ in range(chase_hops):
-        beyond = hit & (qkeys >= st.fence_hi[leaf]) & \
-            (st.sibling[leaf] != NULL_PTR)
-        chased = chased + beyond.astype(jnp.int32)
-        leaf = jnp.where(beyond, st.sibling[leaf], leaf)
-    sound = hit & leaf_sound(st, leaf, qkeys)
+    with jax.named_scope("chase"):
+        chased = jnp.zeros_like(leaf)
+        for _ in range(chase_hops):
+            beyond = hit & (qkeys >= st.fence_hi[leaf]) & \
+                (st.sibling[leaf] != NULL_PTR)
+            chased = chased + beyond.astype(jnp.int32)
+            leaf = jnp.where(beyond, st.sibling[leaf], leaf)
+        sound = hit & leaf_sound(st, leaf, qkeys)
 
     # --- fallback: full root-to-leaf retraversal for miss/unrecovered
     # lanes; skipped entirely when the whole batch hit (the warm case) ---
-    final = lax.cond(
-        jnp.all(sound),
-        lambda: leaf,
-        lambda: jnp.where(sound, leaf, traverse(cfg, st, qkeys).leaf))
-    res = _leaf_probe(st, final, qkeys, kernel_mode)
+    with jax.named_scope("fallback_traverse"):
+        final = lax.cond(
+            jnp.all(sound),
+            lambda: leaf,
+            lambda: jnp.where(sound, leaf, traverse(cfg, st, qkeys).leaf))
+    with jax.named_scope("leaf_probe"):
+        res = _leaf_probe(st, final, qkeys, kernel_mode)
 
     height = st.height.astype(jnp.int32)
     stale = hit & ((chased > 0) | ~sound)
@@ -248,7 +253,8 @@ def _jit_cached_lookup(cfg, st, image, qkeys, chase_hops, kernel_mode):
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def _jit_route(image, qkeys, max_steps):
-    return descend_image(image, qkeys, max_steps)
+    with jax.named_scope("route"):
+        return descend_image(image, qkeys, max_steps)
 
 
 def default_kernel_mode() -> str:
@@ -328,13 +334,15 @@ class IndexCache:
 
     def fill(self, st: TreeState) -> None:
         """(Re)build the image from the current tree state."""
-        self._image, evicted = fill_image(
-            self.cfg, st, levels=self.levels, max_rows=self.capacity_rows)
-        self._rows = np.asarray(self._image["rows"])
-        self._filled = self._rows != ROW_SENTINEL
-        self._valid = np.asarray(self._image["valid"]).copy()
-        self._fnv = np.asarray(self._image["fnv"]).copy()
-        self._root = int(st.root)
+        with span("sherman.cache.refill"):
+            self._image, evicted = fill_image(
+                self.cfg, st, levels=self.levels,
+                max_rows=self.capacity_rows)
+            self._rows = fetch(self._image["rows"], "fill.rows")
+            self._filled = self._rows != ROW_SENTINEL
+            self._valid = fetch(self._image["valid"], "fill.valid").copy()
+            self._fnv = fetch(self._image["fnv"], "fill.fnv").copy()
+            self._root = int(fetch(st.root, "root"))
         self.counters.evictions += evicted
         self.counters.fills += 1
         self.counters.fill_reads += int(self._filled.sum())
@@ -343,8 +351,8 @@ class IndexCache:
 
     def image(self, st: TreeState) -> dict:
         if self._image is None or self._needs_refresh or \
-                int(st.root) != self._root or self._stale_frac() > \
-                self.refresh_frac:
+                int(fetch(st.root, "root")) != self._root or \
+                self._stale_frac() > self.refresh_frac:
             self.fill(st)
         return self._image
 
@@ -360,7 +368,7 @@ class IndexCache:
         # losing one forces a refresh rather than waiting on the threshold
         bad = self._filled & ~valid
         if bad.any():
-            lv = np.asarray(self._image["level"])
+            lv = fetch(self._image["level"], "image.level")
             if (lv[bad] > 1).any() or bad[self._rows == self._root].any():
                 self._needs_refresh = True
 
@@ -370,24 +378,26 @@ class IndexCache:
         (the paper's invalidate-on-stale-detection)."""
         if self._image is None or keys.size == 0:
             return 0
-        lo = np.asarray(self._image["keys"])[:, 0]   # first separator = lo
-        lv = np.asarray(self._image["level"])
-        # covering is keyed over ALL filled level-1 entries (valid or
-        # already dropped): the entry with max lo <= k covers k
-        cand = np.nonzero(self._filled & (lv == 1))[0]
-        if cand.size == 0:
-            return 0
-        order = np.argsort(lo[cand], kind="stable")
-        cand = cand[order]
-        pos = np.searchsorted(lo[cand], np.unique(keys), side="right") - 1
-        cover = np.unique(cand[pos[pos >= 0]])
-        hit = cover[self._valid[cover]]
-        if hit.size:
-            valid = self._valid.copy()
-            valid[hit] = False
-            self._set_valid(valid)
-            self.counters.invalidations += int(hit.size)
-        return int(hit.size)
+        with span("sherman.cache.invalidate"):
+            # the first separator of each entry is its low fence
+            lo = fetch(self._image["keys"], "image.keys")[:, 0]
+            lv = fetch(self._image["level"], "image.level")
+            # covering is keyed over ALL filled level-1 entries (valid or
+            # already dropped): the entry with max lo <= k covers k
+            cand = np.nonzero(self._filled & (lv == 1))[0]
+            if cand.size == 0:
+                return 0
+            order = np.argsort(lo[cand], kind="stable")
+            cand = cand[order]
+            pos = np.searchsorted(lo[cand], np.unique(keys), side="right") - 1
+            cover = np.unique(cand[pos[pos >= 0]])
+            hit = cover[self._valid[cover]]
+            if hit.size:
+                valid = self._valid.copy()
+                valid[hit] = False
+                self._set_valid(valid)
+                self.counters.invalidations += int(hit.size)
+            return int(hit.size)
 
     def sync_versions(self, st: TreeState) -> int:
         """Versioned invalidation: re-read the FNV of every cached row and
@@ -397,17 +407,19 @@ class IndexCache:
         ``take_maintenance`` pricing."""
         if self._image is None:
             return 0
-        safe = np.clip(self._rows, 0, self.cfg.n_nodes - 1)
-        now, freed = jax.device_get(_take_rows((st.fnv, st.free_bit), safe))
-        changed = self._valid & ((now != self._fnv) | freed)
-        n = int(changed.sum())
-        if n:
-            self._set_valid(self._valid & ~changed)
-            self.counters.invalidations += n
-        self.counters.sync_sweeps += 1
-        self.counters.sync_reads += int(self._filled.sum())
-        self._splitty_phases = 0
-        return n
+        with span("sherman.cache.sweep"):
+            safe = np.clip(self._rows, 0, self.cfg.n_nodes - 1)
+            now, freed = fetch(_take_rows((st.fnv, st.free_bit), safe),
+                               "sweep.fnv")
+            changed = self._valid & ((now != self._fnv) | freed)
+            n = int(changed.sum())
+            if n:
+                self._set_valid(self._valid & ~changed)
+                self.counters.invalidations += n
+            self.counters.sync_sweeps += 1
+            self.counters.sync_reads += int(self._filled.sum())
+            self._splitty_phases = 0
+            return n
 
     def end_round(self, st: TreeState) -> None:
         """Cluster-plane coherence tick: one scheduler round elapsed.
@@ -456,16 +468,17 @@ class IndexCache:
         img = self.image(st)
         res, cst = _jit_cached_lookup(self.cfg, st, img, qkeys,
                                       self.chase_hops, self.kernel_mode)
-        hit = np.asarray(cst.hit)
-        stale = np.asarray(cst.stale)
-        reads = np.asarray(cst.remote_reads)
+        hit = fetch(cst.hit, "lookup.hit")
+        stale = fetch(cst.stale, "lookup.stale")
+        reads = fetch(cst.remote_reads, "lookup.remote_reads")
         k = hit.shape[0] if n_valid is None else int(n_valid)
         self.counters.hits += int((hit[:k] & ~stale[:k]).sum())
         self.counters.misses += int((~hit[:k]).sum())
         self.counters.stale += int(stale[:k].sum())
         self.counters.remote_reads += int(reads[:k].sum())
         if stale[:k].any():                  # lazy invalidation on detection
-            self.invalidate_covering(np.asarray(qkeys)[:k][stale[:k]])
+            self.invalidate_covering(
+                fetch(qkeys, "lookup.qkeys")[:k][stale[:k]])
         return res, dict(hit=hit, stale=stale, remote_reads=reads)
 
     def route_hits(self, st: TreeState, qkeys: jax.Array,
@@ -477,7 +490,7 @@ class IndexCache:
             return np.zeros(np.asarray(qkeys).shape[0], bool)
         img = self.image(st)
         _, hit, _ = _jit_route(img, qkeys, self.cfg.max_height)
-        hit = np.asarray(hit)
+        hit = fetch(hit, "route.hit")
         self.note_hits(hit if n_valid is None else hit[:int(n_valid)])
         return hit
 

@@ -387,23 +387,25 @@ def run_repair(cfg, st: TreeState, pend: RepairQueue, iters: int = 2):
     n_internal = jnp.int32(0)
     n_root = jnp.int32(0)
     for _ in range(iters):
-        st, pend, rs = _root_split(cfg, st, pend)
-        n_root = n_root + rs
-        tr = traverse(cfg, st, jnp.maximum(pend.sep, KEY_MIN),
-                      stop_level_arr=pend.level + 1)
-        parent = tr.leaf
-        ok_level = st.level[parent].astype(jnp.int32) == pend.level + 1
-        rank, _ = _rank_by(parent, pend.valid & ok_level, cfg.n_nodes)
-        sel = pend.valid & ok_level & (rank == 0)
-        st, done, full = _internal_insert_once(cfg, st, parent, pend.sep,
-                                               pend.child, sel)
-        pend = pend._replace(valid=pend.valid & ~done)
-        # split the full parents; their separators enter the queue in the
-        # slots of lanes that just completed (compaction via free slots)
-        st, psep, pchild, did, _ = _split_nodes(cfg, st, parent, full)
-        n_internal = n_internal + jnp.sum(did.astype(jnp.int32))
-        pend = _enqueue_pending(pend, psep, pchild,
-                                st.level[parent].astype(jnp.int32), did)
+        with jax.named_scope("run_repair"):
+            st, pend, rs = _root_split(cfg, st, pend)
+            n_root = n_root + rs
+            tr = traverse(cfg, st, jnp.maximum(pend.sep, KEY_MIN),
+                          stop_level_arr=pend.level + 1)
+            parent = tr.leaf
+            ok_level = st.level[parent].astype(jnp.int32) == pend.level + 1
+            rank, _ = _rank_by(parent, pend.valid & ok_level, cfg.n_nodes)
+            sel = pend.valid & ok_level & (rank == 0)
+            st, done, full = _internal_insert_once(cfg, st, parent,
+                                                   pend.sep, pend.child, sel)
+            pend = pend._replace(valid=pend.valid & ~done)
+            # split the full parents; their separators enter the queue in
+            # the slots of lanes that just completed (compaction via free
+            # slots)
+            st, psep, pchild, did, _ = _split_nodes(cfg, st, parent, full)
+            n_internal = n_internal + jnp.sum(did.astype(jnp.int32))
+            pend = _enqueue_pending(pend, psep, pchild,
+                                    st.level[parent].astype(jnp.int32), did)
     return st, pend, n_internal, n_root
 
 
@@ -426,37 +428,42 @@ def write_phase(cfg: TreeConfig, st: TreeState, keys, vals, is_delete,
         repair = RepairQueue.empty(b)
 
     # -- intra-batch dedupe: last op per key wins (DESIGN.md §8) --
-    parked_key = jnp.where(active, keys, -10 - lane)
-    perm = jnp.lexsort((lane, parked_key))
-    inv = jnp.argsort(perm)
-    ks = parked_key[perm]
-    nxt = jnp.concatenate([ks[1:], jnp.full((1,), -7, ks.dtype)])
-    last_of_key = (ks != nxt)[inv]
-    act = active & last_of_key
-    superseded = active & ~last_of_key
+    with jax.named_scope("dedupe"):
+        parked_key = jnp.where(active, keys, -10 - lane)
+        perm = jnp.lexsort((lane, parked_key))
+        inv = jnp.argsort(perm)
+        ks = parked_key[perm]
+        nxt = jnp.concatenate([ks[1:], jnp.full((1,), -7, ks.dtype)])
+        last_of_key = (ks != nxt)[inv]
+        act = active & last_of_key
+        superseded = active & ~last_of_key
 
     # -- route + conflict groups (lock plane) --
     # NOTE: groups are computed over ALL active lanes (pre-dedupe): every
     # client op contends for the leaf lock in the real system even when a
     # later op overwrites its value — dedupe is an application-plane
     # equivalence, not a contention reducer.
-    tr = traverse(cfg, st, keys)
-    groups = hocl.group_by_node(cfg, tr.leaf, cs, active)
-    lock_stats = hocl.lock_phase_stats(cfg, groups, active)
+    with jax.named_scope("traverse"):
+        tr = traverse(cfg, st, keys)
+    with jax.named_scope("group_locks"):
+        groups = hocl.group_by_node(cfg, tr.leaf, cs, active)
+        lock_stats = hocl.lock_phase_stats(cfg, groups, active)
 
     # -- classify against the leaf image --
-    lk = st.keys[tr.leaf]
-    eq = lk == keys[:, None]
-    found = jnp.any(eq, axis=1)
-    slot = jnp.argmax(eq, axis=1).astype(jnp.int32)
-    upd = act & found & ~is_delete
-    dele = act & found & is_delete
-    miss_del = act & ~found & is_delete
-    ins = act & ~found & ~is_delete
+    with jax.named_scope("apply"):
+        lk = st.keys[tr.leaf]
+        eq = lk == keys[:, None]
+        found = jnp.any(eq, axis=1)
+        slot = jnp.argmax(eq, axis=1).astype(jnp.int32)
+        upd = act & found & ~is_delete
+        dele = act & found & is_delete
+        miss_del = act & ~found & is_delete
+        ins = act & ~found & ~is_delete
 
-    st = _apply_updates_deletes(cfg, st, tr.leaf, slot, vals, upd, dele)
-    st, ins_done, ins_defer = _apply_inserts(cfg, st, tr.leaf, keys, vals,
-                                             ins)
+        st = _apply_updates_deletes(cfg, st, tr.leaf, slot, vals, upd,
+                                    dele)
+        st, ins_done, ins_defer = _apply_inserts(cfg, st, tr.leaf, keys,
+                                                 vals, ins)
 
     n_leaf_splits = jnp.int32(0)
     n_same_ms = jnp.int32(0)
@@ -468,25 +475,31 @@ def write_phase(cfg: TreeConfig, st: TreeState, keys, vals, is_delete,
 
     # -- split rounds for overflowing leaves --
     for _ in range(split_rounds):
-        tr2 = traverse(cfg, st, keys)
-        rank0, head = _rank_by(tr2.leaf, ins_defer, cfg.n_nodes)
-        rep = ins_defer & (rank0 == 0)
-        st, sep, new_row, did, same = _split_nodes(cfg, st, tr2.leaf, rep)
-        n_leaf_splits += jnp.sum(did.astype(jnp.int32))
-        n_same_ms += jnp.sum(same.astype(jnp.int32))
-        split_mask = split_mask | did
-        split_same = split_same | same
-        split_row = jnp.where(did, new_row, split_row)
+        with jax.named_scope("split"):
+            tr2 = traverse(cfg, st, keys)
+            rank0, head = _rank_by(tr2.leaf, ins_defer, cfg.n_nodes)
+            rep = ins_defer & (rank0 == 0)
+            st, sep, new_row, did, same = _split_nodes(cfg, st, tr2.leaf,
+                                                       rep)
+            n_leaf_splits += jnp.sum(did.astype(jnp.int32))
+            n_same_ms += jnp.sum(same.astype(jnp.int32))
+            split_mask = split_mask | did
+            split_same = split_same | same
+            split_row = jnp.where(did, new_row, split_row)
         # enqueue separators in the repair queue (free slots)
-        repair = _enqueue_pending(repair, sep, new_row,
-                                  st.level[new_row].astype(jnp.int32), did)
-        st, repair, ni, nr = run_repair(cfg, st, repair, iters=repair_iters)
+        with jax.named_scope("enqueue_repairs"):
+            repair = _enqueue_pending(repair, sep, new_row,
+                                      st.level[new_row].astype(jnp.int32),
+                                      did)
+            st, repair, ni, nr = run_repair(cfg, st, repair,
+                                            iters=repair_iters)
         n_internal += ni
         n_root += nr
         # retry the deferred inserts after the splits
-        tr3 = traverse(cfg, st, keys)
-        st, done2, ins_defer = _apply_inserts(cfg, st, tr3.leaf, keys, vals,
-                                              ins_defer)
+        with jax.named_scope("retry_inserts"):
+            tr3 = traverse(cfg, st, keys)
+            st, done2, ins_defer = _apply_inserts(cfg, st, tr3.leaf, keys,
+                                                  vals, ins_defer)
         ins_done = ins_done | done2
 
     done = (upd | dele | miss_del | ins_done | superseded | ~active)

@@ -8,7 +8,9 @@ every verb's exact service interval and queue/dependency decomposition
 JSON plus derived time series, :mod:`repro.obs.forensics` walks the
 top-K slowest ops' dependency chains backwards into a four-component
 latency attribution, and :mod:`repro.obs.metrics` folds everything into
-the ``RunResult.obs`` registry.
+the ``RunResult.obs`` registry.  :mod:`repro.obs.host` is the program's
+own wall-clock trace: ``sherman.*`` profiler spans on the served path and
+the counted device-to-host reads (DESIGN.md §14).
 """
 from repro.obs.export import timeseries, to_chrome_trace, write_chrome_trace
 from repro.obs.forensics import attribute_ops, span_accounting

@@ -25,11 +25,11 @@ Coherence protocol (documented in docs/DESIGN.md §9):
    periodic sweep that re-reads the FNVs of all cached rows and invalidates
    entries whose version moved (a root split forces a full refresh).
 
-The descent and the leaf probe are shape-static JAX; on a TPU the hot leaf
-search runs through the Pallas kernel in
-:mod:`repro.kernels.leaf_search.kernel`.  Elsewhere it runs the pure-jnp
-oracle :mod:`repro.kernels.leaf_search.ref`; CPU tests run the kernel in
-``interpret`` mode against it.
+The descent and the leaf probe are shape-static JAX; on a TPU the leaf rows
+are read by the Pallas kernel in :mod:`repro.kernels.pool_rows.kernel` and
+searched by the one in :mod:`repro.kernels.leaf_search.kernel`.  Elsewhere
+they run the pure-jnp oracles beside them (``ref.py``); CPU tests run the
+kernels in ``interpret`` mode against those.
 """
 from __future__ import annotations
 
@@ -162,13 +162,24 @@ def descend_image(image: dict, qkeys: jax.Array, max_steps: int
 
 def _leaf_probe(st: TreeState, leaf: jax.Array, qkeys: jax.Array,
                 kernel_mode: str) -> LookupResult:
-    """Search the fetched leaf images: Pallas kernel or jnp reference.
+    """Read and search the fetched leaf images: Pallas kernels or jnp
+    references.
 
     ``kernel_mode``: ``"pallas"`` (compiled, TPU), ``"interpret"``
     (Pallas interpreter — used by CPU tests for kernel parity), ``"ref"``
-    (the pure-jnp oracle from :mod:`repro.kernels.leaf_search.ref`).
+    (the pure-jnp oracles of :mod:`repro.kernels.pool_rows.ref` and
+    :mod:`repro.kernels.leaf_search.ref`).  The leaf rows are read from
+    the pool in its own device layout (:mod:`repro.kernels.pool_rows`).
     """
-    args = (qkeys, st.keys[leaf], st.vals[leaf], st.fev[leaf], st.rev[leaf],
+    cols = (st.keys, st.vals, st.fev, st.rev)
+    if kernel_mode == "ref":
+        from repro.kernels.pool_rows.ref import pool_rows_ref
+        rows = pool_rows_ref(leaf, *cols)
+    else:
+        from repro.kernels.pool_rows.kernel import pool_rows
+        rows = pool_rows(leaf, *cols,
+                         interpret=(kernel_mode == "interpret"))
+    args = (qkeys, *rows,
             st.fnv[leaf].astype(jnp.int32), st.rnv[leaf].astype(jnp.int32),
             st.free_bit[leaf].astype(jnp.int32))
     if kernel_mode == "ref" or qkeys.shape[0] == 0:  # kernel needs a tile

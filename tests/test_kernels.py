@@ -7,6 +7,8 @@ from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.leaf_search.kernel import leaf_search
 from repro.kernels.leaf_search.ref import leaf_search_ref
+from repro.kernels.pool_rows.kernel import pool_rows, stored_transposed
+from repro.kernels.pool_rows.ref import pool_rows_ref
 from repro.kernels.rwkv_scan.kernel import wkv6
 from repro.kernels.rwkv_scan.ref import wkv6_ref
 
@@ -57,6 +59,63 @@ def test_leaf_search(b, f, bt):
     want = leaf_search_ref(*args)
     for g, w in zip(got, want):
         assert (np.asarray(g) == np.asarray(w)).all()
+
+
+@pytest.mark.parametrize("n,f,b", [(1024, 16, 100), (4096, 58, 300),
+                                   (512, 8, 37), (2048, 58, 512)])
+def test_pool_rows(n, f, b):
+    """Rows of an int32 and a uint8 column read in place equal the plain
+    gather: the first row, the last (park) row, repeated ids, and a batch
+    that is no multiple of the kernel's lanes."""
+    assert stored_transposed((n, f)) and n % 128 == 0   # the kernel's path
+    ints = RNG.integers(-2**31, 2**31 - 1, (n, f), dtype=np.int64)
+    cols = (jnp.asarray(ints, jnp.int32),
+            jnp.asarray(RNG.integers(0, 256, (n, f)), jnp.uint8))
+    idx = np.r_[0, n - 1, n - 1, 0, 5, 5, RNG.integers(0, n, b - 6)]
+    idx = jnp.asarray(idx, jnp.int32)
+    got = pool_rows(idx, *cols, interpret=True)
+    for g, w in zip(got, pool_rows_ref(idx, *cols)):
+        assert g.dtype == w.dtype and g.shape == (b, f)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_pool_rows_reads_ids_as_the_gather_does():
+    """Ids outside the pool: negative ones count from the end, the rest
+    clamp, exactly as ``col[idx]``."""
+    n, f = 1024, 16
+    col = jnp.asarray(RNG.integers(0, 1 << 30, (n, f)), jnp.int32)
+    idx = jnp.asarray([-1, -n, -n - 7, n, n + 300, 3], jnp.int32)
+    (got,) = pool_rows(idx, col, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(col[idx]))
+
+
+def test_cached_lookup_kernels_match_reference_on_a_split_tree():
+    """The cached lookup with the Pallas kernels (interpret mode) gives the
+    reference's answers and stats on a tree that split, through a stale
+    image: hits, B-link chases and root retraversals."""
+    from repro.core import ShermanIndex, TreeConfig
+    from repro.core.cache import cached_lookup, fill_image
+    cfg = TreeConfig(n_ms=2, nodes_per_ms=1024, fanout=8,
+                     n_locks_per_ms=512, max_height=8, n_cs=2)
+    idx = ShermanIndex.empty(cfg)
+    keys = RNG.permutation(50_000)[:2_400].astype(np.int32)
+    idx.insert(keys[:600], keys[:600] * 3)
+    stale, _ = fill_image(cfg, idx.state)
+    idx.insert(keys[600:], keys[600:] * 3)
+    assert idx.counters["leaf_splits"] > 0
+    fresh, _ = fill_image(cfg, idx.state)
+    q = jnp.asarray(np.r_[keys[::5], 60_000 + np.arange(20)], jnp.int32)
+    n_stale = []
+    for image in (stale, fresh):
+        r_ref, s_ref = cached_lookup(cfg, idx.state, image, q,
+                                     kernel_mode="ref")
+        r_pal, s_pal = cached_lookup(cfg, idx.state, image, q,
+                                     kernel_mode="interpret")
+        for a, b in zip((*r_ref, *s_ref), (*r_pal, *s_pal)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert np.asarray(r_ref.found).sum() == len(keys[::5])
+        n_stale.append(int(np.asarray(s_ref.stale).sum()))
+    assert n_stale[0] > 0 and n_stale[1] == 0
 
 
 @pytest.mark.parametrize("b,h,t,n,bt,dtype", [

@@ -11,10 +11,12 @@ gives it:
   ``None`` when the run gave it nothing to read.
 
 The window drives the program's served entry, ``repro.cluster.Cluster``'s
-wave API, in closed-loop scheduler rounds in ``run_cluster``'s order:
-``lookup_wave`` for the round's reads, ``write_wave`` for its updates,
-``end_round``.  The traffic is generated before the clock starts, so the
-benchmark knows every op and every written value for the reference.
+wave API, in closed-loop scheduler rounds in ``run_cluster``'s order,
+with one write wave a round: ``lookup_wave`` for the round's reads,
+``write_wave`` for its updates and inserts (each CS's updates, then its
+inserts), ``end_round``.  The traffic is generated before the clock
+starts, so the benchmark knows every op and every written value for the
+reference.
 """
 from __future__ import annotations
 
@@ -219,10 +221,13 @@ def drive_round(cluster, gen: T.Generator, rnd: T.Round,
             answers = cluster.lookup_wave([gen.keys(r)
                                            for r in rnd.read_ranks])
     t_read = time.perf_counter()
-    if rnd.n_updates:
+    if rnd.n_updates or rnd.n_inserts:
         with span("write_wave"):
-            cluster.write_wave([gen.keys(r) for r in rnd.update_ranks],
-                               rnd.update_vals)
+            cluster.write_wave(
+                [gen.keys(np.concatenate([u, i])) for u, i in
+                 zip(rnd.update_ranks, rnd.insert_ranks)],
+                [np.concatenate([u, i]) for u, i in
+                 zip(rnd.update_vals, rnd.insert_vals)])
             jax.block_until_ready(cluster.state)
     t_write = time.perf_counter()
     with span("end_round"):
@@ -235,13 +240,15 @@ def op_latencies(served: list, t_prev: np.ndarray, lanes: int):
     """Closed-loop latency of every op: a client issues its next op when
     its previous one returned, and the op ends when the wave that carries
     it returns.  Lane ``i`` of CS ``cs`` is one client; a round's reads
-    take its first lanes.  ``t_prev[cs, i]`` is when that client's last op
-    returned (updated in place)."""
+    take its first lanes, its updates and then its inserts the next.
+    ``t_prev[cs, i]`` is when that client's last op returned (updated in
+    place)."""
     out = []
     for s in served:
         for cs, reads in enumerate(s.rnd.read_ranks):
             n_r = reads.size
-            n_u = s.rnd.update_ranks[cs].size
+            n_u = (s.rnd.update_ranks[cs].size
+                   + s.rnd.insert_ranks[cs].size)
             done = np.empty(n_r + n_u)
             done[:n_r], done[n_r:] = s.t_read, s.t_write
             out.append(done - t_prev[cs, :n_r + n_u])
@@ -256,9 +263,15 @@ def op_latencies(served: list, t_prev: np.ndarray, lanes: int):
 def check_answers(cell: Cell, seed: int, served: list, window: set) -> dict:
     """Replay every round against the rank-keyed reference: every lookup
     answer (all rounds; those of the window are counted apart) and every
-    acknowledged update, in wave and lane order.  Returns the store."""
+    acknowledged update and insert, in wave and lane order.  A round's
+    updates never name its own inserts (the generator draws them over the
+    records before the round), so its updates and then its inserts is that
+    order.  Returns the store."""
     c = cell.config
-    store = R.Store(R.load_values(seed, c["records"], c["value_mask"]))
+    top = max((int(r[-1]) + 1 for s in served for r in s.rnd.insert_ranks
+               if r.size), default=0)
+    store = R.Store(R.load_values(seed, c["records"], c["value_mask"]),
+                    inserts=max(top - c["records"], 0))
     wrong = wrong_window = looked = 0
     for k, s in enumerate(served):
         for cs, ranks in enumerate(s.rnd.read_ranks):
@@ -274,17 +287,21 @@ def check_answers(cell: Cell, seed: int, served: list, window: set) -> dict:
         store.apply_updates(
             np.concatenate(s.rnd.update_ranks),
             np.concatenate(s.rnd.update_vals))
+        store.apply_inserts(
+            np.concatenate(s.rnd.insert_ranks),
+            np.concatenate(s.rnd.insert_vals))
     return dict(store=store, lookup_wrong=wrong,
                 lookup_wrong_window=wrong_window, looked=looked)
 
 
 def read_back(cluster, gen: T.Generator, store: R.Store, served: list,
               per_cs: int) -> tuple:
-    """Look up every key any round updated, through ``lookup_wave`` at the
-    window's own batch shape, and compare with its last written value.
-    Returns ``(wrong, compared)``."""
+    """Look up every key any round updated or inserted, through
+    ``lookup_wave`` at the window's own batch shape, and compare with its
+    last written value.  Returns ``(wrong, compared)``."""
     ranks = np.unique(np.concatenate(
-        [np.concatenate(s.rnd.update_ranks) for s in served]))
+        [np.concatenate(s.rnd.update_ranks + s.rnd.insert_ranks)
+         for s in served]))
     if not ranks.size:
         return 0, 0
     wave = per_cs * gen.n_cs
@@ -406,10 +423,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                 if time.perf_counter() - t_w >= seconds:
                     break
             window_s = time.perf_counter() - t_w
+    if trace:
+        log(f"bench: trace written in "
+            f"{time.perf_counter() - t_w - window_s:.3f} s")
     after = cluster.combined_counters()
     peak = peak_bytes()
     win = served[first:]
-    ops = sum(s.rnd.n_reads + s.rnd.n_updates for s in win)
+    ops = sum(s.rnd.n_reads + s.rnd.n_updates + s.rnd.n_inserts
+              for s in win)
     if len(win) == len(rounds):
         log(f"bench: WARNING the window used all {len(rounds)} generated "
             f"rounds before {seconds} s")
@@ -446,11 +467,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     correct = (all(v <= lim for v, lim in checks.values())
                and ans["looked"] + rb_n > 0 and rp_n > 0 and swept)
 
+    # ``updates`` counts every write op, inserts among them: the write
+    # metrics are per op that the write wave carried
+    inserts = sum(s.rnd.n_inserts for s in win)
     ctx = dict(cell=cell, ops=ops, window_s=window_s, latencies_s=lat,
                peak_bytes=peak, setup_s=setup_s,
                reads=sum(s.rnd.n_reads for s in win),
-               updates=sum(s.rnd.n_updates for s in win),
-               write_waves=sum(bool(s.rnd.n_updates) for s in win),
+               updates=sum(s.rnd.n_updates for s in win) + inserts,
+               inserts=inserts,
+               write_waves=sum(bool(s.rnd.n_updates + s.rnd.n_inserts)
+                               for s in win),
                counters={k: after[k] - before[k] for k in after},
                device_kind=device_info()["kind"], trace=None)
     device = dict(device_info(), memory_peak_bytes=peak)
@@ -463,17 +489,26 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         events = xtrace.read_events(xtrace.find_xplane(trace_dir))
         lo, hi = xtrace.window_of(events, TRACE_SPAN)
         summ = xtrace.summarize(events, lo, hi)
-        ctx["trace"] = summ
+        ctx.update(trace=summ, trace_events=events, trace_window=(lo, hi))
         device.update(busy_s=summ.busy_s, window_s=summ.window_s)
-        out["breakdown"] = summ.breakdown()
         log(f"bench: trace reduced in {time.perf_counter() - t0:.3f} s "
             f"({len(events)} events)")
         metrics = cell.per_layer
     else:
         metrics = cell.end_to_end
     out["metrics"] = {}
+    t0 = time.perf_counter()
     for m in metrics:
         v = metric_reader(m["name"])(ctx)
         if v is not None:
             out["metrics"][m["name"]] = dict(value=v, unit=m["unit"])
+    if trace:
+        import hostspans
+        red = hostspans.of_run(ctx)
+        if red is not None:
+            bd = red.breakdown()
+            out["breakdown"] = dict(device_ops=bd["device_ops_by_scope"],
+                                    idle_gaps=bd["idle_gaps_program"])
+        log(f"bench: per-layer metrics read in "
+            f"{time.perf_counter() - t0:.3f} s")
     return out

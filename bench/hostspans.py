@@ -3,207 +3,41 @@ device time go, by the spans and named scopes of the served path.
 
 The program marks its host work with ``sherman.*`` spans
 (``repro.obs.host``), on the profiler's clock like the device's ``XLA Ops``,
-and its jitted stages with ``jax.named_scope``.  This module reduces a
-traced window to
+and its jitted stages with ``jax.named_scope``.  This module reduces the
+events of a traced window, as :func:`xtrace.read_events` reads them, to
 
 * **idle by program span**, interval-exact: each idle nanosecond of the
   device goes to the innermost ``sherman.*`` span covering it, else to
   ``host.other``.  The full nesting path is kept, so a metric can ask for
   the idle inside one wave span but outside another;
 * **device time by stage**: each ``XLA Ops`` event is charged to
-  ``<module>/<stage>``.  The module is the ``XLA Modules`` event the op
-  runs in (so same-named ops of two programs stay apart); the stage is the
-  first component of the op's ``tf_op`` metadata (its named scope, or for
-  a copy of an argument the argument's name), else the basename and line
-  of its ``source``, else the op's own name.
+  ``<module>/<stage>``, as the reader names them.
 
-The op metadata (``tf_op``, ``source``, ``program_id``) lives on the
-trace's event metadata, which ``jax.profiler.ProfileData`` does not expose,
-so it is read from the ``.xplane.pb`` with a minimal schema of the XSpace
-proto (names, event metadata and stat metadata; the event lines are left
-unparsed).  Stat names were read by hand from a TPU v5 lite trace.
-
-The per-layer metric readers call :func:`of_run`.  The harness gives a
-reader only ``ctx``, whose trace summary keeps the benchmark's own spans;
-the trace's directory is found as the ``trace_dir`` of the ``run_cell``
-call that is reading its metrics.
+The per-layer metric readers call :func:`of_run` with their ``ctx``, which
+holds the traced window's events (``trace_events``) and bounds
+(``trace_window``); the harness puts the top of the reduction's device
+stages and idle spans in a traced run's result line.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
-import os
-import re
 import sys
-import warnings
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import numpy as np
 
-import harness
 import xtrace
 
-PROGRAM_PREFIX = "sherman."
-#: The span of one counted device-to-host read; its ``what`` names the site.
-FETCH = "sherman.fetch"
+PROGRAM_PREFIX = xtrace.PROGRAM_PREFIX
+FETCH = xtrace.FETCH
 #: The innermost span of idle time no program span covers.
 OTHER = "host.other"
-#: The program's counters of host syncs and cache upkeep, logged beside
-#: the breakdown.
+#: The program's counters of host syncs, cache upkeep, stale cache reads
+#: and splits, logged beside the breakdown.
 UPKEEP = ("host_fetches", "rounds", "cache_fills", "cache_sweeps",
-          "maint_fill_reads", "maint_sync_reads")
-_PROGRAM_ID = re.compile(r"\((\d+)\)$")
-
-
-class Op(NamedTuple):
-    """One ``XLA Ops`` event, charged to its module and stage."""
-    plane: str
-    module: str
-    stage: str
-    start_ns: float
-    dur_ns: float
-
-
-class Span(NamedTuple):
-    name: str
-    start_ns: float
-    end_ns: float
-    what: str = ""          # a fetch span's site
-
-
-# --------------------------------------------------------------------------
-# reading
-# --------------------------------------------------------------------------
-
-def _xspace_class():
-    """A message class for the fields of ``XSpace`` read here.  Maps are
-    declared as their repeated entry messages (the same wire format)."""
-    from google.protobuf import descriptor_pb2, message_factory
-    F = descriptor_pb2.FieldDescriptorProto
-    f = descriptor_pb2.FileDescriptorProto(name="bench_xspace.proto",
-                                           package="bench_xspace")
-
-    def msg(name, *fields):
-        m = f.message_type.add(name=name)
-        for num, fname, ftype, rep, tname in fields:
-            m.field.add(name=fname, number=num, type=ftype,
-                        label=F.LABEL_REPEATED if rep else F.LABEL_OPTIONAL,
-                        type_name=tname and ".bench_xspace." + tname)
-
-    msg("Stat", (1, "metadata_id", F.TYPE_INT64, False, None),
-        (3, "uint64_value", F.TYPE_UINT64, False, None),
-        (4, "int64_value", F.TYPE_INT64, False, None),
-        (5, "str_value", F.TYPE_BYTES, False, None),
-        (7, "ref_value", F.TYPE_UINT64, False, None))
-    msg("EventMetadata", (1, "id", F.TYPE_INT64, False, None),
-        (2, "name", F.TYPE_BYTES, False, None),
-        (5, "stats", F.TYPE_MESSAGE, True, "Stat"))
-    msg("StatMetadata", (1, "id", F.TYPE_INT64, False, None),
-        (2, "name", F.TYPE_BYTES, False, None))
-    msg("EventMetadataEntry", (1, "key", F.TYPE_INT64, False, None),
-        (2, "value", F.TYPE_MESSAGE, False, "EventMetadata"))
-    msg("StatMetadataEntry", (1, "key", F.TYPE_INT64, False, None),
-        (2, "value", F.TYPE_MESSAGE, False, "StatMetadata"))
-    msg("Plane", (2, "name", F.TYPE_BYTES, False, None),
-        (4, "event_metadata", F.TYPE_MESSAGE, True, "EventMetadataEntry"),
-        (5, "stat_metadata", F.TYPE_MESSAGE, True, "StatMetadataEntry"))
-    msg("Space", (1, "planes", F.TYPE_MESSAGE, True, "Plane"))
-    return message_factory.GetMessages([f])["bench_xspace.Space"]
-
-
-def op_metadata(path: str) -> dict:
-    """``{(plane, program_id, op name): (tf_op, source)}`` of every device
-    op in the trace, ``None`` where a stat is absent."""
-    space = _xspace_class()()
-    with open(path, "rb") as fh:
-        space.ParseFromString(fh.read())
-    out = {}
-    for plane in space.planes:
-        pname = plane.name.decode()
-        if not xtrace.DEVICE_PLANE.match(pname):
-            continue
-        stat_names = {e.key: e.value.name.decode()
-                      for e in plane.stat_metadata}
-        for entry in plane.event_metadata:
-            em = entry.value
-            got = {}
-            for s in em.stats:
-                what = stat_names.get(s.metadata_id)
-                if what in ("tf_op", "source"):
-                    got[what] = (stat_names.get(s.ref_value, "")
-                                 if s.ref_value else
-                                 s.str_value.decode(errors="replace"))
-                elif what == "program_id":
-                    got[what] = s.uint64_value or s.int64_value
-            out[(pname, got.get("program_id"),
-                 em.name.decode(errors="replace"))] = (got.get("tf_op"),
-                                                        got.get("source"))
-    return out
-
-
-def stage_of(tf_op: Optional[str], source: Optional[str], op: str) -> str:
-    """``jit(f)/descend/jit(searchsorted)/while:`` -> ``descend``;
-    ``st.keys:`` -> ``st.keys``; else ``cache.py:232`` from the source;
-    else the op's name."""
-    if tf_op:
-        parts = [p for p in tf_op.rstrip(":").split("/") if p]
-        if parts and parts[0].startswith("jit("):
-            parts = parts[1:]
-        if parts:
-            return parts[0].rstrip(":")
-    if source:
-        return os.path.basename(source)
-    return op
-
-
-def read(path: str) -> tuple:
-    """``(ops, spans)``: every device op with its module and stage, and the
-    host spans of the program and the benchmark."""
-    import jax
-    meta = op_metadata(path)
-    pd = jax.profiler.ProfileData.from_file(path)
-    ops, spans = [], []
-    for plane in pd.planes:
-        if xtrace.DEVICE_PLANE.match(plane.name):
-            lines = {ln.name: ln for ln in plane.lines}
-            if xtrace.OPS_LINE not in lines:
-                continue
-            mods = sorted((float(e.start_ns), float(e.end_ns), e.name)
-                          for e in lines[xtrace.MODULES_LINE].events) \
-                if xtrace.MODULES_LINE in lines else []
-            evs = [(e.name, float(e.start_ns), float(e.duration_ns))
-                   for e in lines[xtrace.OPS_LINE].events]
-            # the module an op runs in is the module event covering it
-            at = np.searchsorted(np.array([m[0] for m in mods]),
-                                 np.array([t for _, t, _ in evs]),
-                                 side="right") - 1
-            stages = {}
-            for (name, t, dur), k in zip(evs, at.tolist()):
-                mod = mods[k][2] if k >= 0 and t < mods[k][1] else None
-                key = (mod, name)
-                if key not in stages:
-                    m = _PROGRAM_ID.search(mod or "")
-                    tf_op, source = meta.get(
-                        (plane.name, m and int(m.group(1)), name),
-                        (None, None))
-                    stages[key] = (xtrace.module_of(mod) if mod
-                                   else "unknown",
-                                   stage_of(tf_op, source,
-                                            xtrace.op_of(name)))
-                ops.append(Op(plane.name, *stages[key], t, dur))
-        elif plane.name == xtrace.HOST_PLANE:
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name.startswith((PROGRAM_PREFIX,
-                                          xtrace.SPAN_PREFIX)):
-                        what = ""
-                        if e.name == FETCH:
-                            with warnings.catch_warnings():
-                                warnings.simplefilter("ignore")
-                                what = str(dict(e.stats).get("what", ""))
-                        spans.append(Span(e.name, float(e.start_ns),
-                                          float(e.end_ns), what))
-    return ops, spans
+          "maint_fill_reads", "maint_sync_reads", "cache_stale",
+          "leaf_splits", "internal_splits")
 
 
 # --------------------------------------------------------------------------
@@ -289,10 +123,13 @@ class Reduction:
                                ("idle_by_span_path", paths))}
 
 
-def reduce(ops, spans, lo: float, hi: float) -> Reduction:
-    """Reduce the ops and spans inside ``[lo, hi)`` (the traced window)."""
+def reduce(events, lo: float, hi: float) -> Reduction:
+    """Reduce the events inside ``[lo, hi)`` (the traced window): the
+    device's ``XLA Ops`` and the program's host spans."""
+    ops = [e for e in events if e.line == xtrace.OPS_LINE]
     planes = sorted({o.plane for o in ops})
-    program = [s for s in spans if s.name.startswith(PROGRAM_PREFIX)]
+    program = [e for e in events if e.plane == xtrace.HOST_PLANE
+               and e.name.startswith(PROGRAM_PREFIX)]
     segs = _segments(program, lo, hi)
     a = np.array([s[0] for s in segs])
     b = np.array([s[1] for s in segs])
@@ -300,8 +137,8 @@ def reduce(ops, spans, lo: float, hi: float) -> Reduction:
     stage = collections.Counter()
     for p in planes:
         mine = [o for o in ops if o.plane == p]
-        busy = xtrace._union(((o.start_ns, o.start_ns + o.dur_ns)
-                              for o in mine), lo, hi)
+        busy = xtrace._union([o.start_ns for o in mine],
+                             [o.end_ns for o in mine], lo, hi)
         gaps, cursor = [], lo
         for s, e in busy + [[hi, hi]]:
             if s > cursor:
@@ -329,37 +166,15 @@ def reduce(ops, spans, lo: float, hi: float) -> Reduction:
 # for the metric readers
 # --------------------------------------------------------------------------
 
-_done: dict = {}
-
-
-def _trace_dir() -> Optional[str]:
-    """``trace_dir`` of the ``run_cell`` call on the stack, if any."""
-    f = sys._getframe(1)
-    while f is not None:
-        if f.f_code.co_name == "run_cell" and "trace_dir" in f.f_locals:
-            return f.f_locals["trace_dir"]
-        f = f.f_back
-    return None
-
-
 def of_run(ctx) -> Optional[Reduction]:
-    """The reduction of the traced run whose metrics are being read, once
-    per trace; ``None`` without a trace, without device ops, or without
-    program spans (a program that has none)."""
-    if ctx.get("trace") is None:
+    """The reduction of the traced window that ``ctx`` holds, made once a
+    run and kept in ``ctx``; ``None`` without a trace, without device ops,
+    or without program spans (a program that has none)."""
+    if ctx.get("trace") is None or "trace_events" not in ctx:
         return None
-    d = _trace_dir()
-    if not d:
-        return None
-    path = xtrace.find_xplane(d)
-    if path not in _done:
-        ops, spans = read(path)
-        lo, hi = xtrace.window_of(
-            [xtrace.Event(xtrace.HOST_PLANE, "", s.name, s.start_ns,
-                          s.end_ns - s.start_ns) for s in spans],
-            harness.TRACE_SPAN)
-        red = reduce(ops, spans, lo, hi)
-        if not (ops and red.span_s):
+    if "program_trace" not in ctx:
+        red = reduce(ctx["trace_events"], *ctx["trace_window"])
+        if not (red.stage_s and red.span_s):
             red = None
         else:
             for k, v in red.breakdown().items():
@@ -367,5 +182,5 @@ def of_run(ctx) -> Optional[Reduction]:
             upkeep = {k: ctx["counters"].get(k) for k in UPKEEP}
             print(f"bench: program counters = {upkeep}", file=sys.stderr,
                   flush=True)
-        _done[path] = red
-    return _done[path]
+        ctx["program_trace"] = red
+    return ctx["program_trace"]

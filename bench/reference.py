@@ -2,10 +2,11 @@
 
 Both import nothing of the program.
 
-* :class:`Store` is what the index owes, keyed by load rank: the load's
-  values drawn from the seed, overlaid with every acknowledged update in
-  wave and lane order.  A lookup of a live record must return that record's
-  latest value; an update, once its wave returned, must read back.
+* :class:`Store` is what the index owes, keyed by rank: the load's values
+  drawn from the seed, and a slot for every rank the traffic inserts,
+  overlaid with every acknowledged update and insert in wave and lane
+  order.  A lookup of a live record must return that record's latest
+  value; an update or insert, once its wave returned, must read back.
 * :func:`replay` is the priced timeline's specification: a per-verb event
   loop over one merged verb trace.  Every verb is posted once its gates
   (``dep``/``dep2`` completions and its ``at`` floor) allow, is served in
@@ -44,21 +45,38 @@ def last_writes(ranks: np.ndarray, vals: np.ndarray):
 
 
 class Store:
-    """Rank-keyed model of the index: no sort of the load is needed."""
+    """Rank-keyed model of the index: no sort of the load is needed.
 
-    def __init__(self, values: np.ndarray):
-        self.values = values
+    Ranks below ``records`` are the load; the ``inserts`` ranks above it
+    hold nothing until an insert names them."""
+
+    def __init__(self, values: np.ndarray, inserts: int = 0):
+        self.records = int(values.size)
+        self.values = (np.concatenate([values, np.zeros(inserts, np.int32)])
+                       if inserts else values)
+        self.inserted = np.zeros(inserts, bool)
 
     def check_reads(self, ranks, got, found) -> int:
-        """Wrong answers among one wave's lookups of live records."""
+        """Wrong answers among one wave's lookups: a rank not yet inserted
+        is never a right answer."""
         want = self.values[ranks]
-        return int(np.count_nonzero(~np.asarray(found, bool)
+        live = np.ones(ranks.size, bool)
+        fresh = ranks >= self.records
+        if fresh.any():
+            live[fresh] = self.inserted[ranks[fresh] - self.records]
+        return int(np.count_nonzero(~np.asarray(found, bool) | ~live
                                     | (np.asarray(got) != want)))
 
     def apply_updates(self, ranks, vals) -> None:
         if ranks.size:
             uniq, v = last_writes(ranks, vals)
             self.values[uniq] = v
+
+    def apply_inserts(self, ranks, vals) -> None:
+        """Acknowledged inserts: the last lane to write a rank wins."""
+        if ranks.size:
+            self.inserted[ranks - self.records] = True
+            self.apply_updates(ranks, vals)
 
 
 def replay(trace: dict, net: dict, n_ms: int, onchip: bool) -> dict:

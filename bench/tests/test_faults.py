@@ -13,13 +13,19 @@ def _numbers(out):
     return {k: v for k, (v, _) in out["checks"].items()}
 
 
+@pytest.mark.parametrize("name,traffic", [
+    ("wi-zipf-c24m", None), ("wi-unif-c24m", None),
+    # YCSB D: each CS's write batch is all inserts, so ``half_batch``
+    # leaves out half of every CS's inserts
+    ("ro-zipf-c64m", "ycsb-d")])
 @pytest.mark.parametrize("fault,number", [
     (faults.state_unchanged, "readback_wrong"),
     (faults.half_batch, "readback_wrong"),
     (faults.answer_altered, "lookup_wrong"),
 ])
-def test_fault_is_caught(fault, number):
-    out = run(tiny_cell("wi-zipf-c24m"), tamper=fault, kernel_mode="ref")
+def test_fault_is_caught(fault, number, name, traffic):
+    out = run(tiny_cell(name, traffic=traffic), tamper=fault,
+              kernel_mode="ref")
     assert not out["correct"]
     assert _numbers(out)[number] > 0
 

@@ -11,11 +11,22 @@ import pytest
 import harness
 import hostspans
 import xtrace
-from hostspans import Op, Span
 
-DEV = "/device:TPU:0"
+DEV, HOST = "/device:TPU:0", "/host:CPU"
 DATA = os.path.join(os.path.dirname(__file__), "data",
                     "ro_round_program_trace.json.gz")
+
+
+def Op(plane, module, stage, start_ns, dur_ns):
+    """A device op as ``xtrace.read_events`` gives it."""
+    return xtrace.Event(plane, xtrace.OPS_LINE, stage, start_ns, dur_ns,
+                        module=module, stage=stage)
+
+
+def Span(name, start_ns, end_ns, what=""):
+    """A host span as ``xtrace.read_events`` gives it."""
+    return xtrace.Event(HOST, "python3", name, start_ns, end_ns - start_ns,
+                        what=what)
 
 
 def synthetic():
@@ -39,7 +50,7 @@ def synthetic():
 
 def test_idle_is_attributed_interval_exactly():
     ops, spans = synthetic()
-    red = hostspans.reduce(ops, spans, 0, 100)
+    red = hostspans.reduce(ops + spans, 0, 100)
     ns = {k: v * 1e9 for k, v in red.idle_by_span().items()}
     # gaps [0,10) [40,50) [60,90) [95,100); the gap [40,50) spans the two
     # sibling cs_lookups and is split between them at 45 (its midpoint
@@ -63,7 +74,7 @@ def test_device_time_by_stage_keeps_modules_apart():
     ops = [Op(DEV, "_jit_cached_lookup", "copy.3", 0, 10),
            Op(DEV, "_jit_write_phase", "copy.3", 20, 30),
            Op(DEV, "_jit_write_phase", "copy.3", 60, 5)]
-    red = hostspans.reduce(ops, [Span("sherman.write_wave", 0, 100)],
+    red = hostspans.reduce(ops + [Span("sherman.write_wave", 0, 100)],
                            0, 100)
     assert red.stage_s == pytest.approx({
         "_jit_cached_lookup/copy.3": 10e-9, "_jit_write_phase/copy.3": 35e-9})
@@ -73,13 +84,13 @@ def test_device_time_by_stage_keeps_modules_apart():
 
 
 def test_stage_names():
-    assert hostspans.stage_of(
+    assert xtrace.stage_of(
         "jit(_jit_cached_lookup)/descend/jit(searchsorted)/while:", None,
         "while.52") == "descend"
-    assert hostspans.stage_of("st.keys:", None, "copy.430") == "st.keys"
-    assert hostspans.stage_of(None, "/x/src/repro/core/cache.py:232",
+    assert xtrace.stage_of("st.keys:", None, "copy.430") == "st.keys"
+    assert xtrace.stage_of(None, "/x/src/repro/core/cache.py:232",
                               "cond.3.clone") == "cache.py:232"
-    assert hostspans.stage_of(None, None, "while.213") == "while.213"
+    assert xtrace.stage_of(None, None, "while.213") == "while.213"
 
 
 XSPACE = """
@@ -135,23 +146,30 @@ def _trace_dir(tmp_path, text=XSPACE):
 
 
 def test_read_takes_module_stage_and_site_from_the_file(tmp_path):
-    path = xtrace.find_xplane(_trace_dir(tmp_path))
-    ops, spans = hostspans.read(path)
-    assert [(o.module, o.stage, o.start_ns, o.dur_ns) for o in ops] == [
+    events = xtrace.read_events(xtrace.find_xplane(_trace_dir(tmp_path)))
+    assert [(e.module, e.stage, e.start_ns, e.dur_ns) for e in events
+            if e.line == xtrace.OPS_LINE] == [
         ("_jit_cached_lookup", "st.keys", 1005, 20),
         ("_jit_write_phase", "copy.3", 1055, 10),
         ("_jit_write_phase", "write.py:470", 1066, 4)]
-    assert [(s.name, s.start_ns, s.end_ns, s.what) for s in spans] == [
+    assert [(e.name, e.start_ns, e.end_ns, e.what) for e in events
+            if e.plane == HOST] == [
         ("bench.window", 1000, 1090, ""),
         ("sherman.lookup_wave", 1000, 1045, ""),
         ("sherman.fetch", 1025, 1035, "lookup.hit"),
         ("sherman.write_wave", 1050, 1085, "")]
+    assert [(e.name, e.start_ns) for e in events
+            if e.line == xtrace.MODULES_LINE] == [
+        ("jit__jit_cached_lookup(11)", 1000),
+        ("jit__jit_write_phase(22)", 1050)]
 
 
 def run_cell(ctx, trace_dir, reader):
-    """Stands in for ``harness.run_cell``: a reader finds the trace as the
-    ``trace_dir`` of the ``run_cell`` frame reading it."""
-    return reader(ctx)
+    """Stands in for ``harness.run_cell``: the trace is read once, and a
+    reader finds its events and window in its context."""
+    events = xtrace.read_events(xtrace.find_xplane(trace_dir))
+    return reader(dict(ctx, trace_events=events, trace_window=xtrace.
+                       window_of(events, harness.TRACE_SPAN)))
 
 
 def _ctx(**kw):
@@ -200,7 +218,7 @@ def test_readers_find_nothing_without_program_spans(tmp_path):
               "host_fetches_per_round", "replay_ns_per_verb"):
         r = harness.metric_reader(n)
         assert run_cell(ctx, d, r) is None
-        assert r(_ctx()) in (None, 100.0)      # no run_cell on the stack
+        assert r(_ctx()) in (None, 100.0)      # no trace in ctx
         assert run_cell(_ctx(trace=None), d, r) in (None, 100.0)
 
 
@@ -210,11 +228,9 @@ def test_recorded_chip_round():
     ops = [Op(rec["plane"], m, s, t, d) for m, s, t, d in rec["ops"]]
     spans = [Span(*s) for s in rec["spans"]]
     lo, hi = rec["lo"], rec["hi"]
-    red = hostspans.reduce(ops, spans, lo, hi)
-    # the same busy time as the benchmark's own reduction
-    summ = xtrace.summarize(
-        [xtrace.Event(rec["plane"], "XLA Ops", o.stage, o.start_ns,
-                      o.dur_ns) for o in ops], lo, hi)
+    red = hostspans.reduce(ops + spans, lo, hi)
+    # the same busy time as the benchmark's own reduction of those events
+    summ = xtrace.summarize(ops + spans, lo, hi)
     assert sum(red.idle_by_path.values()) == \
         pytest.approx(summ.window_s - summ.busy_s)
     assert sum(red.stage_s.values()) == pytest.approx(sum(

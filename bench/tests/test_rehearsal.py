@@ -32,6 +32,20 @@ def test_cell_runs_correct(name):
         {"lookup_wrong": 0, "readback_wrong": 0, "replay_wrong": 0}
 
 
+def test_ycsb_d_rehearsal():
+    """The YCSB D mix (95% read, 5% insert, latest) through a tiny cell:
+    every answer right, every insert read back, inserts counted as write
+    ops."""
+    lines = []
+    cell = tiny_cell("ro-zipf-c64m", traffic="ycsb-d")
+    out = run(cell, log=lines.append)
+    assert {k: v for k, (v, _) in out["checks"].items()} == \
+        {"lookup_wrong": 0, "readback_wrong": 0, "replay_wrong": 0}
+    assert out["correct"] and out["failed"] == 0 and out["compiles"] == 0
+    read_back = next(m for m in lines if "keys read back" in m)
+    assert int(read_back.split(" window lookups, ")[1].split()[0]) > 0
+
+
 def test_per_layer_readers_on_a_summary():
     """Every per-layer reader of BENCHMARK.json returns a number from a
     run's context, and nothing where the run gave it nothing to read."""

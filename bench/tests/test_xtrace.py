@@ -41,19 +41,12 @@ def test_synthetic_busy_idle_and_attribution():
     # busy: [10, 40) + [50, 60) + [95, 100) = 45 ns
     assert s.busy_s == pytest.approx(45e-9)
     assert s.idle_pct == pytest.approx(55.0)
-    # gaps: [0,10) write, [40,50) price (midpoint 45), [60,95) midpoint
-    # 77.5 lies in no span
-    assert s.idle_by_span == pytest.approx({
-        "bench.write_wave": 10e-9, "bench.price_merged_phase": 10e-9,
-        "host.other": 35e-9})
     assert s.modules("_jit_write_phase") == pytest.approx(30e-9)
     assert s.modules("_jit_cached_lookup", "_jit_route") == \
         pytest.approx(10e-9)
     assert s.kernel_s("leaf_search") == pytest.approx(10e-9)
     assert s.span_s["bench.price_merged_phase"] == pytest.approx(6e-9)
-    bd = s.breakdown()
-    assert bd["device_ops"][0] == ["while.1", pytest.approx(30e-9)]
-    assert len(bd["idle_gaps"]) == 3
+    assert s.op_s["while.1"] == pytest.approx(30e-9)
 
 
 def test_names():
@@ -84,10 +77,6 @@ def test_recorded_chip_excerpt():
     busy = _union_brute([(e.start_ns, e.end_ns) for e in ops], lo, hi)
     assert s.busy_s == pytest.approx(busy * 1e-9)
     assert 0 < s.busy_s < s.window_s
-    # every idle second lies in the one covering span
-    assert sum(s.idle_by_span.values()) == \
-        pytest.approx(s.window_s - s.busy_s)
-    assert set(s.idle_by_span) == {"bench.lookup_wave"}
     # 8 CSs: 8 cached-lookup programs, one 256-lane kernel call each
     kernel = [e for e in ops if xtrace.op_of(e.name).split(".")[0]
               == "leaf_search" and lo <= e.start_ns < hi]

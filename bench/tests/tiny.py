@@ -15,8 +15,15 @@ TINY = dict(n_ms=2, nodes_per_ms=1024, records=40_000, keyspace=1 << 16,
             n_cs=2, cache_bytes_per_cs=1013 * 8)
 
 
-def tiny_cell(name: str = "wi-zipf-c24m") -> harness.Cell:
+def tiny_cell(name: str = "wi-zipf-c24m",
+              traffic: str = None) -> harness.Cell:
+    """Cell ``name`` at the tiny size; ``traffic`` names a mix of
+    ``bench/traffic`` to run in place of the cell's own."""
     cell = harness.load_cell(name)
+    if traffic is not None:
+        with open(os.path.join(harness.BENCH, "traffic",
+                               traffic + ".json")) as f:
+            cell.traffic = json.load(f)
     cell.config = dict(cell.config, **TINY)
     cell.traffic = dict(cell.traffic, lanes_per_cs=64)
     return cell

@@ -8,13 +8,25 @@ key, so hot ranks land far apart in the key space (YCSB's
 ScrambledZipfian).  The scramble is a bijection on ``[0, keyspace)`` when
 ``keyspace`` is a power of two.
 
-The Zipf draw, the scramble and the per-batch op counts are copies of the
-program's (``repro.workloads.keygen`` and ``WorkloadSpec.batch_counts``),
-kept here so that a change to the program cannot move the traffic.
+An insert names a fresh rank: ranks ``records, records + 1, ...`` are
+handed out in phase order (warm, then window), then round, then CS, then
+lane, and scrambled to their keys (YCSB's ``insertorder=hashed``).  The
+``live`` records of a round are the load and every insert of the rounds
+before it; ``zipfian`` and ``uniform`` draw over the load ranks, ``latest``
+(YCSB's SkewedLatestGenerator) over the live ones, newest hottest.  A
+round's reads and updates therefore never name a rank inserted in its own
+round or later.
+
+The Zipf and latest draws, the scramble and the per-batch op counts are
+copies of the program's (``repro.workloads.keygen`` and
+``WorkloadSpec.batch_counts``), kept here so that a change to the program
+cannot move the traffic.
 
 Each round ``r`` draws from generators seeded by ``(seed, phase, r, cs)``,
-so a round's inputs do not depend on how many rounds were generated before
-it: the same seed gives the same rounds, however long the window runs.
+and its first insert rank is a sum of the op counts of the rounds before
+it, which depend on nothing but the mix: a round's inputs do not depend on
+how many rounds were generated before it, and the same seed gives the same
+rounds, however long the window runs.
 """
 from __future__ import annotations
 
@@ -24,8 +36,8 @@ import numpy as np
 
 SCRAMBLE = 2_654_435_761            # odd: a bijection modulo a power of two
 GOLDEN = 0.6180339887498949         # low-discrepancy remainder sequence
-OP_KINDS = ("read", "update")       # what the rank-keyed reference can check
-DISTRIBUTIONS = ("zipfian", "uniform")
+OP_KINDS = ("read", "update", "insert")  # what the reference can check
+DISTRIBUTIONS = ("zipfian", "uniform", "latest")
 PHASES = {"warm": 1, "window": 2}
 
 
@@ -105,10 +117,15 @@ def check_mix(mix: dict) -> None:
 
 @dataclasses.dataclass
 class Round:
-    """One scheduler round: every CS's reads and updates (in CS order)."""
+    """One scheduler round: every CS's reads, updates and inserts (in CS
+    order).  ``live`` is the number of records before the round's inserts:
+    its first insert rank."""
     read_ranks: list            # per CS, int64 ranks (maybe empty)
     update_ranks: list          # per CS, int64 ranks
     update_vals: list           # per CS, int32 values
+    insert_ranks: list          # per CS, int64 fresh consecutive ranks
+    insert_vals: list           # per CS, int32 values
+    live: int
 
     @property
     def n_reads(self) -> int:
@@ -117,6 +134,10 @@ class Round:
     @property
     def n_updates(self) -> int:
         return sum(r.size for r in self.update_ranks)
+
+    @property
+    def n_inserts(self) -> int:
+        return sum(r.size for r in self.insert_ranks)
 
 
 class Generator:
@@ -132,23 +153,67 @@ class Generator:
         self.seed = int(seed) % (1 << 64)
         self.zipf = (Zipf(self.records, mix["theta"])
                      if mix["distribution"] == "zipfian" else None)
+        self._inserted = [0]    # inserts of a phase's rounds [0, k)
 
-    def _ranks(self, rng, n: int) -> np.ndarray:
-        if self.zipf is not None:
-            return self.zipf.ranks(rng, n)
-        return rng.integers(0, self.records, size=n).astype(np.int64)
+    def counts(self, r: int) -> list:
+        """Each CS's op counts in round ``r`` of any phase."""
+        return [batch_counts(self.mix["ops"], self.lanes,
+                             salt=r * self.n_cs + cs)
+                for cs in range(self.n_cs)]
+
+    def _inserts_before(self, r: int) -> int:
+        """Inserts in rounds ``[0, r)`` of a phase: a sum of op counts,
+        which depend only on the round's index."""
+        if not self.mix["ops"].get("insert"):
+            return 0
+        while len(self._inserted) <= r:
+            k = len(self._inserted) - 1
+            self._inserted.append(self._inserted[-1] + sum(
+                c["insert"] for c in self.counts(k)))
+        return self._inserted[r]
+
+    def live(self, phase: str, r: int) -> int:
+        """Records before round ``r`` of ``phase``: the load, every warm
+        round's inserts if ``phase`` is the window, and the phase's own
+        earlier rounds'."""
+        warm = (self._inserts_before(int(self.mix["warm_rounds"]))
+                if phase == "window" else 0)
+        return self.records + warm + self._inserts_before(r)
+
+    def _draw(self, live: int):
+        """The mix's rank draw for a round with ``live`` records."""
+        dist = self.mix["distribution"]
+        if dist == "zipfian":
+            return self.zipf.ranks
+        if dist == "latest":
+            zipf = Zipf(live, self.mix["theta"])
+            return lambda rng, n: (live - 1) - zipf.ranks(rng, n)
+        return lambda rng, n: rng.integers(0, self.records,
+                                           size=n).astype(np.int64)
 
     def round(self, phase: str, r: int) -> Round:
-        reads, ups, vals = [], [], []
-        for cs in range(self.n_cs):
+        live = self.live(phase, r)
+        draw = self._draw(live)
+        reads, ups, vals, ins, ins_vals = [], [], [], [], []
+        nxt = live
+        for cs, c in enumerate(self.counts(r)):
             rng = np.random.default_rng((self.seed, PHASES[phase], r, cs))
-            c = batch_counts(self.mix["ops"], self.lanes,
-                             salt=r * self.n_cs + cs)
-            reads.append(self._ranks(rng, c["read"]))
-            ups.append(self._ranks(rng, c["update"]))
+            reads.append(draw(rng, c["read"]))
+            ups.append(draw(rng, c["update"]))
             vals.append(rng.integers(0, self.value_mask, c["update"]
                                      ).astype(np.int32))
-        return Round(reads, ups, vals)
+            n = c["insert"]
+            ins.append(np.arange(nxt, nxt + n, dtype=np.int64))
+            ins_vals.append(rng.integers(0, self.value_mask, n
+                                         ).astype(np.int32) if n
+                            else np.zeros(0, np.int32))
+            nxt += n
+        if nxt > self.keyspace:
+            raise ValueError(
+                f"traffic {self.mix['name']!r}: {phase} round {r} inserts "
+                f"rank {nxt - 1}, past the keyspace of {self.keyspace} "
+                f"where the scramble is a bijection")
+        return Round(reads, ups, vals, ins, ins_vals, live)
 
     def rounds(self, phase: str, first: int, count: int) -> list:
         return [self.round(phase, r) for r in range(first, first + count)]

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -117,10 +118,11 @@ class ClusterNode:
         trace's input dict (per-lane remote reads + target leaves, padded
         to the dispatch bucket with an ``active`` prefix mask)."""
         with span("sherman.cs_lookup", cs=self.cs_id):
-            keys = jnp.asarray(keys, jnp.int32)
+            if not isinstance(keys, jax.Array):
+                keys = np.asarray(keys, np.int32)      # padded on the host
             n = keys.shape[0]
             m = bucket_size(n)
-            kp = pad_to_bucket(keys, m)
+            kp = jnp.asarray(pad_to_bucket(keys, m), jnp.int32)
             active = np.arange(m) < n
             c = self.counters
             if self.cache.enabled:

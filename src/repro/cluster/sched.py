@@ -49,6 +49,7 @@ from repro.core import hocl, netsim, verbs as V
 from repro.core.api import (REPAIR_CAP, _jit_write_phase, bucket_size,
                             pad_to_bucket, run_repair_drain,
                             write_phase_progress, write_stats_dict)
+from repro.core.cache import IMAGE_REFILL_WHY
 from repro.core.netsim import Features, NetConfig, SHERMAN
 from repro.core.tree import TreeConfig, TreeState, bulkload
 from repro.core.write import RepairQueue
@@ -109,7 +110,7 @@ class Cluster:
             "cas_msgs": 0, "sim_time_s": 0.0, "merged_waves": 0,
             "rounds": 0, "cross_cs_conflicts": 0,
             "stacked_phases": 0, "internal_splits": 0, "root_splits": 0,
-            "host_fetches": 0,
+            "host_fetches": 0, "repair_dropped": 0, "blink_hops": 0,
         }
         self.latencies_write: list[np.ndarray] = []
         self.latencies_read: list[np.ndarray] = []
@@ -291,9 +292,9 @@ class Cluster:
                                for i, k, _ in segs])
         n = keys.size
         m = bucket_size(n)
-        keys_j = pad_to_bucket(jnp.asarray(keys), m)
-        vals_j = pad_to_bucket(jnp.asarray(vals), m)
-        cs_j = pad_to_bucket(jnp.asarray(cs_l), m)
+        keys_j = jnp.asarray(pad_to_bucket(keys, m))
+        vals_j = jnp.asarray(pad_to_bucket(vals, m))
+        cs_j = jnp.asarray(pad_to_bucket(cs_l, m))
         cs_np = np.pad(cs_l, (0, m - n), constant_values=-1)
         is_del = jnp.broadcast_to(jnp.asarray(bool(is_delete)), (m,))
         active = jnp.arange(m) < n
@@ -308,7 +309,7 @@ class Cluster:
                 node.counters["write_ops"] += k.size
                 node.counters["ops"] += k.size
                 if node.cache.enabled:
-                    kp = pad_to_bucket(jnp.asarray(k), bucket_size(k.size))
+                    kp = jnp.asarray(pad_to_bucket(k, bucket_size(k.size)))
                     h = node.cache.route_hits(self.state, kp,
                                               n_valid=k.size)
                     route_hits[off:off + k.size] = h[:k.size]
@@ -330,8 +331,12 @@ class Cluster:
                     stats.n_internal_splits, "stats.n_internal_splits"))
                 c["root_splits"] += int(fetch(stats.n_root_splits,
                                               "stats.n_root_splits"))
-                self._repair_backlog = int(fetch(stats.repair_backlog,
-                                                 "stats.repair_backlog"))
+                backlog, dropped, hops = fetch(
+                    (stats.repair_backlog, stats.repair_dropped,
+                     stats.blink_hops), "stats.repair_backlog")
+                self._repair_backlog = int(backlog)
+                c["repair_dropped"] += int(dropped)
+                c["blink_hops"] += int(hops)
                 for i, _, _ in segs:
                     self.nodes[i].note_write_phase(
                         sd, act_np & (cs_np == i),
@@ -407,10 +412,11 @@ class Cluster:
             return
         with span("sherman.write.drain"):
             (self.state, self.repair, n_int, n_root,
-             self._repair_backlog) = run_repair_drain(
+             self._repair_backlog, n_drop) = run_repair_drain(
                 self.cfg, self.state, self.repair, sync_every)
         self.counters["internal_splits"] += n_int
         self.counters["root_splits"] += n_root
+        self.counters["repair_dropped"] += n_drop
         if self._repair_backlog:
             raise RuntimeError("cluster repair queue did not drain")
 
@@ -512,6 +518,14 @@ class Cluster:
         # reads each priced
         caches = [n.cache.counters for n in self.nodes]
         out["cache_fills"] = sum(c.fills for c in caches)
+        for why in IMAGE_REFILL_WHY:
+            out["image_refills_" + why] = sum(c.refills[why] for c in caches)
+        out["image_refills"] = sum(out["image_refills_" + why]
+                                   for why in IMAGE_REFILL_WHY)
+        # sibling hops: the write routes' (wave scope) and the cached
+        # lookups' chases
+        out["blink_hops"] = self.counters["blink_hops"] + sum(
+            c.blink_hops for c in caches)
         out["cache_sweeps"] = sum(c.sync_sweeps for c in caches)
         out["maint_fill_reads"] = sum(c.fill_reads for c in caches)
         out["maint_sync_reads"] = sum(c.sync_reads for c in caches)

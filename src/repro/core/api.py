@@ -20,9 +20,12 @@ Shape stability (the jit-cache discipline every driver relies on):
   each compile once per bucket instead of once per batch length;
 * the repair queue has a **fixed capacity** (:data:`REPAIR_CAP`)
   independent of the batch size, so repair steps never trigger a
-  shape-churn recompile (overflowing separators are dropped, which is
-  safe under the B-link invariant — a later traversal rediscovers the
-  half-split);
+  shape-churn recompile.  Nothing is dropped at the capacity: a node
+  splits only when the queue has a free slot for its separator, so a full
+  parent waits (its child's separator stays queued) and a full leaf waits
+  (its insert stays deferred to the next phase); a split round's leaf
+  splits take at most half the free slots and leave the rest to the
+  cascade, so the drain always makes progress;
 * the tree state (and the repair queue) are **donated** to the jitted
   phases, so XLA updates them in place instead of copying the pool every
   phase.
@@ -50,9 +53,10 @@ __all__ = ["ShermanIndex", "TreeConfig", "Features", "FG_PLUS", "SHERMAN",
            "pad_to_bucket"]
 
 #: Fixed capacity of every driver-owned repair queue.  Independent of the
-#: batch size so ``_jit_repair``/``_jit_write_phase`` compile once; large
-#: enough that one wave's half-splits never overflow in practice (a
-#: dropped separator is still safe — B-link rediscovery).
+#: batch size so ``_jit_repair``/``_jit_write_phase`` compile once.  Splits
+#: wait for a free slot rather than drop a separator (``core/write.py``),
+#: so a burst of more splits than this takes more repair steps, not a
+#: parent that never learns of its child.
 REPAIR_CAP = 256
 
 #: Smallest dispatch bucket; batches below this pad up to it.
@@ -64,13 +68,16 @@ def bucket_size(n: int) -> int:
     return max(BUCKET_MIN, 1 << max(0, int(n) - 1).bit_length())
 
 
-def pad_to_bucket(arr: jnp.ndarray, m: int, fill=0) -> jnp.ndarray:
-    """Pad a [n, ...] batch array to bucket length ``m`` with ``fill``."""
+def pad_to_bucket(arr, m: int, fill=0):
+    """Pad a [n, ...] batch array to bucket length ``m`` with ``fill``.
+    A NumPy array is padded on the host, so that a batch of a new length
+    compiles nothing on the device."""
     n = arr.shape[0]
     if n == m:
         return arr
-    pad = jnp.full((m - n,) + arr.shape[1:], fill, arr.dtype)
-    return jnp.concatenate([arr, pad])
+    xp = np if isinstance(arr, np.ndarray) else jnp
+    pad = xp.full((m - n,) + arr.shape[1:], fill, arr.dtype)
+    return xp.concatenate([arr, pad])
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 7))
@@ -98,10 +105,11 @@ def _jit_range_cached(cfg, st, lo, count, max_leaves, cache_image):
 def _jit_repair(cfg, st, repair):
     """One fixed-shape repair step.  Returns the post-step pending count
     so the drain loop can sync the host every k iterations instead of
-    forcing a device round trip per iteration."""
-    st, repair, ni, nr = write.run_repair(cfg, st, repair, iters=2)
+    forcing a device round trip per iteration, and the separators the
+    step dropped (0, see :data:`REPAIR_CAP`)."""
+    st, repair, ni, nr, nd = write.run_repair(cfg, st, repair, iters=2)
     pending = jnp.sum(repair.valid.astype(jnp.int32))
-    return st, repair, ni, nr, pending
+    return st, repair, ni, nr, pending, nd
 
 
 def run_repair_drain(cfg, state, repair, sync_every: int = 4):
@@ -109,30 +117,30 @@ def run_repair_drain(cfg, state, repair, sync_every: int = 4):
 
     Runs :func:`_jit_repair` steps back-to-back and checks the jitted
     step's pending count and split counters on the host only every
-    ``sync_every`` iterations.  The drain runs until the queue is empty,
-    or until a check finds that the steps since the last one neither
-    shrank the queue nor split a node.  Returns ``(state, repair,
-    n_internal, n_root, backlog)``; ``backlog`` is the host-side pending
-    count after the drain (0 when it completed).  Shared by
-    ``ShermanIndex.drain_repairs`` and the cluster scheduler's wave-scope
-    drain so their sync semantics cannot diverge.
+    ``sync_every`` iterations, in one read of the steps' counts.  The
+    drain runs until the queue is empty, or until a check finds that the
+    steps since the last one neither shrank the queue nor split a node.
+    Returns ``(state, repair, n_internal, n_root, backlog, n_dropped)``;
+    ``backlog`` is the host-side pending count after the drain (0 when it
+    completed).  Shared by ``ShermanIndex.drain_repairs`` and the cluster
+    scheduler's wave-scope drain so their sync semantics cannot diverge.
     """
-    n_int = n_root = 0
+    n_int = n_root = n_drop = 0
     window, last = [], None
     for it in itertools.count():
-        state, repair, ni, nr, pending = _jit_repair(cfg, state, repair)
-        window.append((ni, nr))
+        state, repair, ni, nr, pending, nd = _jit_repair(cfg, state, repair)
+        window.append((ni, nr, nd))
         # one step usually clears a write batch's handful of separators,
         # so check after the first step too, then every sync_every
         if it and (it + 1) % sync_every:
             continue
-        ni_w = sum(int(fetch(a, "repair.n_internal")) for a, _ in window)
-        nr_w = sum(int(fetch(b, "repair.n_root")) for _, b in window)
-        n_int, n_root, window = n_int + ni_w, n_root + nr_w, []
-        backlog = int(fetch(pending, "repair.pending"))
+        counts, backlog = fetch((window, pending), "repair.counts")
+        ni_w, nr_w, nd_w = (int(sum(c)) for c in zip(*counts))
+        n_int, n_root, n_drop = n_int + ni_w, n_root + nr_w, n_drop + nd_w
+        window, backlog = [], int(backlog)
         if not backlog or (last is not None and backlog >= last
                            and not (ni_w or nr_w)):
-            return state, repair, n_int, n_root, backlog
+            return state, repair, n_int, n_root, backlog, n_drop
         last = backlog
 
 
@@ -342,7 +350,7 @@ class ShermanIndex:
         if not self._repair_backlog:
             return
         (self.state, self._repair, n_int, n_root,
-         self._repair_backlog) = run_repair_drain(
+         self._repair_backlog, _) = run_repair_drain(
             self.cfg, self.state, self._repair, sync_every)
         self.counters["internal_splits"] += n_int
         self.counters["root_splits"] += n_root

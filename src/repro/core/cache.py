@@ -47,6 +47,10 @@ from repro.core.tree import EMPTY_KEY, NULL_PTR, TreeConfig, TreeState
 from repro.obs.host import fetch, span
 
 ROW_SENTINEL = np.int32(2**31 - 1)     # "no row" padding in the sorted image
+#: Why a live image is rebuilt: the root moved, an entry above level 1 (or
+#: the root's) went stale, or the stale share of entries passed the
+#: threshold.  The first fill of an empty image is not a refill.
+IMAGE_REFILL_WHY = ("root", "upper_level", "threshold")
 
 
 class CacheStats(NamedTuple):
@@ -289,6 +293,9 @@ class CacheCounters:
     remote_reads: int = 0    # leaf/node reads issued by cached lookups
     fill_reads: int = 0      # whole-node reads spent (re)filling the image
     sync_reads: int = 0      # small version reads spent on sync sweeps
+    blink_hops: int = 0      # sibling hops of cached lookups' chases
+    refills: dict = dataclasses.field(       # refills of a live image, by
+        default_factory=lambda: dict.fromkeys(IMAGE_REFILL_WHY, 0))  # why
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -332,6 +339,8 @@ class IndexCache:
         self._root = -1
         self._splitty_phases = 0
         self._needs_refresh = True
+        self._refresh_why = "cold"      # why _needs_refresh was set
+        self._lo_level = None           # host (low fence, level) per row
         self._maint_taken = (0, 0)      # (fill_reads, sync_reads) drained
 
     # -- image lifecycle ---------------------------------------------------
@@ -343,9 +352,10 @@ class IndexCache:
     def cached_bytes(self) -> int:
         return int(self._valid.sum()) * self.cfg.node_bytes
 
-    def fill(self, st: TreeState) -> None:
-        """(Re)build the image from the current tree state."""
-        with span("sherman.cache.refill"):
+    def fill(self, st: TreeState, why: str = "cold") -> None:
+        """(Re)build the image from the current tree state; ``why`` is
+        ``cold`` for a first fill, else one of :data:`IMAGE_REFILL_WHY`."""
+        with span("sherman.cache.refill", why=why):
             self._image, evicted = fill_image(
                 self.cfg, st, levels=self.levels,
                 max_rows=self.capacity_rows)
@@ -356,20 +366,35 @@ class IndexCache:
             self._root = int(fetch(st.root, "root"))
         self.counters.evictions += evicted
         self.counters.fills += 1
+        if why != "cold":
+            self.counters.refills[why] += 1
         self.counters.fill_reads += int(self._filled.sum())
         self._splitty_phases = 0
         self._needs_refresh = False
+        self._lo_level = None
 
     def image(self, st: TreeState) -> dict:
-        if self._image is None or self._needs_refresh or \
-                int(fetch(st.root, "root")) != self._root or \
-                self._stale_frac() > self.refresh_frac:
+        if self._image is None:
             self.fill(st)
+        elif self._needs_refresh:
+            self.fill(st, self._refresh_why)
+        elif int(fetch(st.root, "root")) != self._root:
+            self.fill(st, "root")
+        elif self._stale_frac() > self.refresh_frac:
+            self.fill(st, "threshold")
         return self._image
 
     def _stale_frac(self) -> float:
         n = int(self._filled.sum())
         return (int((self._filled & ~self._valid).sum()) / n) if n else 0.0
+
+    def _host_lo_level(self) -> tuple[np.ndarray, np.ndarray]:
+        """Each image row's low fence (its first separator) and level, on
+        the host: one read of the image per fill, kept until the next."""
+        if self._lo_level is None:
+            self._lo_level = fetch((self._image["keys"][:, 0],
+                                    self._image["level"]), "image.lo_level")
+        return self._lo_level
 
     def _set_valid(self, valid: np.ndarray) -> None:
         self._valid = valid
@@ -378,10 +403,11 @@ class IndexCache:
         # key range — far more than its 1/rows share of _stale_frac — so
         # losing one forces a refresh rather than waiting on the threshold
         bad = self._filled & ~valid
-        if bad.any():
-            lv = fetch(self._image["level"], "image.level")
+        if bad.any() and not self._needs_refresh:
+            _, lv = self._host_lo_level()
             if (lv[bad] > 1).any() or bad[self._rows == self._root].any():
                 self._needs_refresh = True
+                self._refresh_why = "upper_level"
 
     # -- invalidation ------------------------------------------------------
     def invalidate_covering(self, keys: np.ndarray) -> int:
@@ -391,8 +417,7 @@ class IndexCache:
             return 0
         with span("sherman.cache.invalidate"):
             # the first separator of each entry is its low fence
-            lo = fetch(self._image["keys"], "image.keys")[:, 0]
-            lv = fetch(self._image["level"], "image.level")
+            lo, lv = self._host_lo_level()
             # covering is keyed over ALL filled level-1 entries (valid or
             # already dropped): the entry with max lo <= k covers k
             cand = np.nonzero(self._filled & (lv == 1))[0]
@@ -459,6 +484,7 @@ class IndexCache:
             return
         if n_root:
             self._needs_refresh = True
+            self._refresh_why = "root"
             return
         if n_leaf or n_internal:
             self._splitty_phases += 1
@@ -487,6 +513,11 @@ class IndexCache:
         self.counters.misses += int((~hit[:k]).sum())
         self.counters.stale += int(stale[:k].sum())
         self.counters.remote_reads += int(reads[:k].sum())
+        # a hit lane's reads past its one leaf read are its chase's sibling
+        # hops; a lane the chase did not recover spent the whole budget
+        # (and is priced a retraversal on top)
+        self.counters.blink_hops += int(np.minimum(
+            reads[:k][hit[:k]] - 1, self.chase_hops).sum())
         if stale[:k].any():                  # lazy invalidation on detection
             self.invalidate_covering(
                 fetch(qkeys, "lookup.qkeys")[:k][stale[:k]])
@@ -543,6 +574,8 @@ class IndexCache:
         self._splitty_phases = 0
         self._rounds_since_sync = 0
         self._needs_refresh = True
+        self._refresh_why = "cold"
+        self._lo_level = None
 
     def export_state(self) -> tuple[Optional[dict], dict]:
         """Snapshot the cache's full mutable state as
@@ -558,6 +591,7 @@ class IndexCache:
             rounds_since_sync=self._rounds_since_sync,
             splitty_phases=self._splitty_phases,
             needs_refresh=self._needs_refresh,
+            refresh_why=self._refresh_why,
             maint_taken=list(self._maint_taken),
         )
         return image, scalars
@@ -578,6 +612,8 @@ class IndexCache:
         self._rounds_since_sync = int(scalars["rounds_since_sync"])
         self._splitty_phases = int(scalars["splitty_phases"])
         self._needs_refresh = bool(scalars["needs_refresh"])
+        self._refresh_why = scalars.get("refresh_why", "cold")
+        self._lo_level = None
         self._maint_taken = tuple(scalars["maint_taken"])
 
     # -- reporting ---------------------------------------------------------

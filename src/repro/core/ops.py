@@ -26,17 +26,21 @@ class TraceB(NamedTuple):
     path: jax.Array          # [max_height, B] node ids visited (may repeat)
     path_level: jax.Array    # [max_height, B] level of each visited node
     hops: jax.Array          # [B] number of distinct descents (netsim)
+    chased: jax.Array        # [B] B-link sibling hops taken on the way
 
 
 def _descend_once(st: TreeState, node: jax.Array, qkeys: jax.Array,
-                  stop_level: jax.Array, chase_hops: int) -> jax.Array:
-    """One traversal step: bounded B-link sibling chase, then one descent."""
+                  stop_level: jax.Array, chase_hops: int):
+    """One traversal step: bounded B-link sibling chase, then one descent.
+    Returns ``(node, hops)``: the next node and the sibling hops taken."""
     # --- sibling chase (paper §4.2.1): key beyond the fence => go right ---
+    hops = jnp.zeros_like(node)
     for _ in range(chase_hops):
         fh = st.fence_hi[node]
         sib = st.sibling[node]
         chase = (qkeys >= fh) & (sib != NULL_PTR)
         node = jnp.where(chase, sib, node)
+        hops = hops + chase.astype(jnp.int32)
     lv = st.level[node].astype(jnp.int32)
     nk = st.keys[node]                       # [B, F]
     nv = st.vals[node]
@@ -44,7 +48,7 @@ def _descend_once(st: TreeState, node: jax.Array, qkeys: jax.Array,
     le = valid & (nk <= qkeys[:, None])
     j = jnp.maximum(jnp.sum(le.astype(jnp.int32), axis=1) - 1, 0)
     child = jnp.take_along_axis(nv, j[:, None], axis=1)[:, 0]
-    return jnp.where(lv > stop_level, child, node)
+    return jnp.where(lv > stop_level, child, node), hops
 
 
 def traverse(cfg: TreeConfig, st: TreeState, qkeys: jax.Array,
@@ -63,15 +67,17 @@ def traverse(cfg: TreeConfig, st: TreeState, qkeys: jax.Array,
     stop = (jnp.full((b,), stop_level, jnp.int32)
             if stop_level_arr is None else stop_level_arr.astype(jnp.int32))
 
-    def body(node, _):
-        nxt = _descend_once(st, node, qkeys, stop, chase_hops)
-        return nxt, (node, st.level[node].astype(jnp.int32))
+    def body(carry, _):
+        node, chased = carry
+        nxt, n = _descend_once(st, node, qkeys, stop, chase_hops)
+        return (nxt, chased + n), (node, st.level[node].astype(jnp.int32))
 
-    final, (path, plevel) = lax.scan(body, node0, None, length=cfg.max_height)
+    (final, chased), (path, plevel) = lax.scan(
+        body, (node0, jnp.zeros_like(node0)), None, length=cfg.max_height)
     hops = 1 + jnp.sum((path[1:] != path[:-1]).astype(jnp.int32), axis=0)
     final, extra = _chase_right(st.fence_hi, st.sibling, qkeys, final)
     return TraceB(leaf=final, path=path, path_level=plevel,
-                  hops=hops + extra)
+                  hops=hops + extra, chased=chased + extra)
 
 
 @jax.jit

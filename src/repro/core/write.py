@@ -13,7 +13,10 @@ execution (DESIGN.md §8):
 * separator insertion into parents may cascade; unfinished cascades are safe
   to defer thanks to the B-link sibling property (Lehman&Yao) and are
   returned as a *repair queue* that the driver completes in later phases —
-  the SIMD analogue of the classic half-split state.
+  the SIMD analogue of the classic half-split state.  The queue has a fixed
+  capacity, and a node splits only when the queue has a free slot for its
+  separator (:func:`_first`): no separator is ever dropped, and a node that
+  must wait keeps its own separator, or its insert, pending.
 
 All functions are shape-static and jit/shard_map friendly.
 """
@@ -141,6 +144,10 @@ class WriteStats(NamedTuple):
     flat_remote_cas: jax.Array    # [] no-hierarchy baseline CAS count
     handovers: jax.Array          # []
     repair_backlog: jax.Array     # [] separators left in the repair queue
+    repair_dropped: jax.Array     # [] separators lost at the queue's
+                                  #    capacity (0: splits wait for a slot)
+    blink_hops: jax.Array         # [] B-link sibling hops of the active
+                                  #    lanes' route to their leaves
 
 
 class RepairQueue(NamedTuple):
@@ -159,15 +166,26 @@ class RepairQueue(NamedTuple):
             valid=jnp.zeros((q,), bool))
 
 
+def _free_slots(pend: RepairQueue) -> jax.Array:
+    return jnp.sum((~pend.valid).astype(jnp.int32))
+
+
+def _first(mask: jax.Array, n: jax.Array) -> jax.Array:
+    """The first ``n`` set lanes of ``mask``, in lane order.  A split is
+    allowed only as far as the repair queue has free slots for the
+    separators it makes."""
+    return mask & (jnp.cumsum(mask.astype(jnp.int32)) <= n)
+
+
 def _enqueue_pending(pend: RepairQueue, sep: jax.Array, child: jax.Array,
-                     level: jax.Array, did: jax.Array) -> RepairQueue:
+                     level: jax.Array, did: jax.Array):
     """Insert the ``did`` lanes' separators into the queue's free slots.
 
-    The r-th new entry (by lane order) lands in the r-th free slot;
-    entries beyond the free capacity are dropped, which is safe under the
-    B-link invariant — the half-split is rediscovered by a later
-    traversal.  Shared by the write phase's split rounds and the repair
-    cascade.
+    The r-th new entry (by lane order) lands in the r-th free slot.
+    Returns ``(queue, dropped)``: every caller splits only as many nodes
+    as there are free slots, so ``dropped``, the entries beyond the free
+    capacity, is 0; it is counted so that a run shows it.  Shared by the
+    write phase's split rounds and the repair cascade.
     """
     q = pend.sep.shape[0]
     free = ~pend.valid
@@ -187,7 +205,8 @@ def _enqueue_pending(pend: RepairQueue, sep: jax.Array, child: jax.Array,
                                              mode="drop")[:q],
         level=pad(pend.level, 0).at[tgt].set(jnp.where(can, level, 0),
                                              mode="drop")[:q],
-        valid=pad(pend.valid, False).at[tgt].set(can, mode="drop")[:q])
+        valid=pad(pend.valid, False).at[tgt].set(can, mode="drop")[:q]), \
+        jnp.sum((did & ~can).astype(jnp.int32))
 
 
 # --------------------------------------------------------------------------
@@ -380,12 +399,16 @@ def _root_split(cfg, st: TreeState, pend: RepairQueue):
 def run_repair(cfg, st: TreeState, pend: RepairQueue, iters: int = 2):
     """Complete half-splits: push pending separators into parents.
 
-    Each iteration handles ≤1 root split and ≤1 separator per parent, may
-    split full parents (emitting new pending entries at the next level), and
-    leaves the remainder in the queue — safe under B-link semantics.
+    Each iteration handles ≤1 root split and ≤1 separator per parent, and
+    splits as many full parents as the queue has free slots for their own
+    separators (at the next level).  A separator whose full parent could
+    not split stays in the queue for the next iteration, as does the
+    remainder — safe under B-link semantics.  Returns ``(state, queue,
+    internal splits, root splits, separators dropped)``.
     """
     n_internal = jnp.int32(0)
     n_root = jnp.int32(0)
+    n_dropped = jnp.int32(0)
     for _ in range(iters):
         with jax.named_scope("run_repair"):
             st, pend, rs = _root_split(cfg, st, pend)
@@ -399,14 +422,16 @@ def run_repair(cfg, st: TreeState, pend: RepairQueue, iters: int = 2):
             st, done, full = _internal_insert_once(cfg, st, parent,
                                                    pend.sep, pend.child, sel)
             pend = pend._replace(valid=pend.valid & ~done)
-            # split the full parents; their separators enter the queue in
-            # the slots of lanes that just completed (compaction via free
-            # slots)
-            st, psep, pchild, did, _ = _split_nodes(cfg, st, parent, full)
+            # split the full parents the queue has room for; their
+            # separators enter the queue in the slots of lanes that just
+            # completed (compaction via free slots)
+            st, psep, pchild, did, _ = _split_nodes(
+                cfg, st, parent, _first(full, _free_slots(pend)))
             n_internal = n_internal + jnp.sum(did.astype(jnp.int32))
-            pend = _enqueue_pending(pend, psep, pchild,
-                                    st.level[parent].astype(jnp.int32), did)
-    return st, pend, n_internal, n_root
+            pend, dropped = _enqueue_pending(
+                pend, psep, pchild, st.level[parent].astype(jnp.int32), did)
+            n_dropped = n_dropped + dropped
+    return st, pend, n_internal, n_root, n_dropped
 
 
 # --------------------------------------------------------------------------
@@ -469,6 +494,7 @@ def write_phase(cfg: TreeConfig, st: TreeState, keys, vals, is_delete,
     n_same_ms = jnp.int32(0)
     n_internal = jnp.int32(0)
     n_root = jnp.int32(0)
+    n_dropped = jnp.int32(0)
     split_mask = jnp.zeros((b,), bool)
     split_same = jnp.zeros((b,), bool)
     split_row = jnp.full((b,), jnp.int32(cfg.park_row))
@@ -478,7 +504,12 @@ def write_phase(cfg: TreeConfig, st: TreeState, keys, vals, is_delete,
         with jax.named_scope("split"):
             tr2 = traverse(cfg, st, keys)
             rank0, head = _rank_by(tr2.leaf, ins_defer, cfg.n_nodes)
-            rep = ins_defer & (rank0 == 0)
+            # the leaf splits take at most half the free slots: the other
+            # half is for the parents they fill (on a packed tree each
+            # leaf split splits its parent), so the cascade completes in
+            # this phase's repair and, with a slot always left, can always
+            # drain.  A leaf left unsplit keeps its insert deferred
+            rep = _first(ins_defer & (rank0 == 0), _free_slots(repair) // 2)
             st, sep, new_row, did, same = _split_nodes(cfg, st, tr2.leaf,
                                                        rep)
             n_leaf_splits += jnp.sum(did.astype(jnp.int32))
@@ -488,13 +519,14 @@ def write_phase(cfg: TreeConfig, st: TreeState, keys, vals, is_delete,
             split_row = jnp.where(did, new_row, split_row)
         # enqueue separators in the repair queue (free slots)
         with jax.named_scope("enqueue_repairs"):
-            repair = _enqueue_pending(repair, sep, new_row,
-                                      st.level[new_row].astype(jnp.int32),
-                                      did)
-            st, repair, ni, nr = run_repair(cfg, st, repair,
-                                            iters=repair_iters)
+            repair, dropped = _enqueue_pending(
+                repair, sep, new_row, st.level[new_row].astype(jnp.int32),
+                did)
+            st, repair, ni, nr, nd = run_repair(cfg, st, repair,
+                                                iters=repair_iters)
         n_internal += ni
         n_root += nr
+        n_dropped += dropped + nd
         # retry the deferred inserts after the splits
         with jax.named_scope("retry_inserts"):
             tr3 = traverse(cfg, st, keys)
@@ -521,5 +553,7 @@ def write_phase(cfg: TreeConfig, st: TreeState, keys, vals, is_delete,
         flat_remote_cas=lock_stats["flat_remote_cas"],
         handovers=lock_stats["handovers"],
         repair_backlog=jnp.sum(repair.valid.astype(jnp.int32)),
+        repair_dropped=n_dropped,
+        blink_hops=jnp.sum(jnp.where(active, tr.chased, 0)),
     )
     return st, done, stats, repair

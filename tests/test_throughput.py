@@ -191,5 +191,5 @@ def test_drain_repairs_syncs_in_batches():
     from repro.core.api import REPAIR_CAP
     st = bulkload(CFG, np.arange(0, 4_000, 7), np.arange(572))
     out = _jit_repair(CFG, st, RepairQueue.empty(REPAIR_CAP))
-    assert len(out) == 5                       # ..., ni, nr, pending
-    assert int(out[4]) == 0
+    assert len(out) == 6                 # ..., ni, nr, pending, dropped
+    assert int(out[4]) == 0 and int(out[5]) == 0
